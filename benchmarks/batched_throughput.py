@@ -407,6 +407,9 @@ def main() -> None:
                          "schema-versioned BENCH_serving.json artifact "
                          "(validated before writing)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     # Env must be set before anything touches the jax backend.
     from repro.distributed.sharding import (

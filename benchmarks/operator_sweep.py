@@ -61,7 +61,7 @@ SWEEP_REFINE = {1: 2, 2: 1, 4: 1, 6: 0, 8: 0}
 
 # Lanes swept per p for the paop_pallas assembly (requested lanes; each
 # row also records the lane that actually ran).
-SWEEP_LANES = ("interpret", "compiled")
+SWEEP_LANES = ("interpret", "auto")
 
 
 def run(
@@ -172,6 +172,9 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_operator_sweep.json",
                     help="artifact path (schema-validated before writing)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     rows = run(
         ps=tuple(args.p),

@@ -74,6 +74,9 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help=f"comma list from {SUITES}")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     only = args.only.split(",") if args.only else SUITES
 
     t0 = time.time()

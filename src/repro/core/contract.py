@@ -13,9 +13,20 @@ Index conventions match the paper: ``B[q, i] = phi_i(xi_q)``,
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["forward_grad", "backward_grad_t", "interp3d", "interp3d_t"]
+__all__ = [
+    "forward_grad", "backward_grad_t", "interp3d", "interp3d_t", "einsum"
+]
+
+
+def einsum(spec, *operands):
+    """``jnp.einsum`` at full precision.  On a TPU an f32 contraction
+    otherwise runs one bf16 MXU pass (~3 significant digits), which
+    leaves an f32 operator too inexact for the Krylov iteration to reach
+    its tolerances; elsewhere this is plain einsum."""
+    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST)
 
 
 def forward_grad(x, B, G):
@@ -26,16 +37,16 @@ def forward_grad(x, B, G):
     (d_xi, d_eta, d_zeta) and trailing axes (qz, qy, qx).
     """
     # X contraction: two channels (sm0[0/1] of the paper).
-    u = jnp.einsum("...zyx,qx->...zyq", x, B)
-    v = jnp.einsum("...zyx,qx->...zyq", x, G)
+    u = einsum("...zyx,qx->...zyq", x, B)
+    v = einsum("...zyx,qx->...zyq", x, G)
     # Y contraction: three channels (sm1[0/1/2]).
-    d_xi = jnp.einsum("...zyq,ry->...zrq", v, B)
-    d_eta = jnp.einsum("...zyq,ry->...zrq", u, G)
-    u_xy = jnp.einsum("...zyq,ry->...zrq", u, B)
+    d_xi = einsum("...zyq,ry->...zrq", v, B)
+    d_eta = einsum("...zyq,ry->...zrq", u, G)
+    u_xy = einsum("...zyq,ry->...zrq", u, B)
     # Z contraction.
-    g_xi = jnp.einsum("...zrq,sz->...srq", d_xi, B)
-    g_eta = jnp.einsum("...zrq,sz->...srq", d_eta, B)
-    g_zeta = jnp.einsum("...zrq,sz->...srq", u_xy, G)
+    g_xi = einsum("...zrq,sz->...srq", d_xi, B)
+    g_eta = einsum("...zrq,sz->...srq", d_eta, B)
+    g_zeta = einsum("...zrq,sz->...srq", u_xy, G)
     return jnp.stack([g_xi, g_eta, g_zeta], axis=-4)
 
 
@@ -49,9 +60,9 @@ def backward_grad_t(q, B, G):
     """
 
     def sweep(t, tx, ty, tz):
-        t = jnp.einsum("...srq,sz->...zrq", t, tz)  # Z: tmpZ
-        t = jnp.einsum("...zrq,ry->...zyq", t, ty)  # Y: tmpY
-        return jnp.einsum("...zyq,qx->...zyx", t, tx)  # X + accumulate
+        t = einsum("...srq,sz->...zrq", t, tz)  # Z: tmpZ
+        t = einsum("...zrq,ry->...zyq", t, ty)  # Y: tmpY
+        return einsum("...zyq,qx->...zyx", t, tx)  # X + accumulate
 
     return (
         sweep(q[..., 0, :, :, :], G, B, B)
@@ -62,13 +73,13 @@ def backward_grad_t(q, B, G):
 
 def interp3d(x, B):
     """Pure interpolation to quadrature points (used by mass-type terms)."""
-    x = jnp.einsum("...zyx,qx->...zyq", x, B)
-    x = jnp.einsum("...zyq,ry->...zrq", x, B)
-    return jnp.einsum("...zrq,sz->...srq", x, B)
+    x = einsum("...zyx,qx->...zyq", x, B)
+    x = einsum("...zyq,ry->...zrq", x, B)
+    return einsum("...zrq,sz->...srq", x, B)
 
 
 def interp3d_t(x, B):
     """Transpose of :func:`interp3d`."""
-    x = jnp.einsum("...srq,sz->...zrq", x, B)
-    x = jnp.einsum("...zrq,ry->...zyq", x, B)
-    return jnp.einsum("...zyq,qx->...zyx", x, B)
+    x = einsum("...srq,sz->...zrq", x, B)
+    x = einsum("...zrq,ry->...zyq", x, B)
+    return einsum("...zyq,qx->...zyx", x, B)

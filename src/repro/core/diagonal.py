@@ -20,6 +20,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.core.contract import einsum
+
 __all__ = ["element_diagonal"]
 
 
@@ -31,7 +33,7 @@ def element_diagonal(lam_w, mu_w, jinv, B, G):
     """
     per_elem_j = jinv.ndim == 3
     jjt = (
-        jnp.einsum("emj,enj->emn", jinv, jinv)
+        einsum("emj,enj->emn", jinv, jinv)
         if per_elem_j
         else jinv @ jinv.T
     )
@@ -49,8 +51,8 @@ def element_diagonal(lam_w, mu_w, jinv, B, G):
             ux = u_table(0, m, n)
             uy = u_table(1, m, n)
             uz = u_table(2, m, n)
-            s_lam = jnp.einsum("ezyx,zc,yb,xa->ecba", lam_w, uz, uy, ux)
-            s_mu = jnp.einsum("ezyx,zc,yb,xa->ecba", mu_w, uz, uy, ux)
+            s_lam = einsum("ezyx,zc,yb,xa->ecba", lam_w, uz, uy, ux)
+            s_mu = einsum("ezyx,zc,yb,xa->ecba", mu_w, uz, uy, ux)
             if per_elem_j:
                 coef_c = jinv[:, m, :] * jinv[:, n, :]  # (ne, 3)
                 out = out + coef_c[:, :, None, None, None] * (

@@ -47,7 +47,7 @@ from repro.core import pa_baseline as _base
 from repro.core import pa_sumfact as _sf
 from repro.core import paop as _paop
 from repro.core.basis import basis_tables
-from repro.kernels.pa_elasticity.ops import resolve_lane
+from repro.kernels.pa_elasticity.ops import check_compiled_dtype, resolve_lane
 from repro.core.geometry import (
     MATERIALS_BEAM,
     make_quadrature_data,
@@ -96,12 +96,14 @@ class ElasticityOperator:
         self.dtype = dtype
         self.tables = space.tables
         # Resolved at construction, so this attribute is the report of
-        # which Pallas lane actually runs ("compiled" or "interpret"):
-        # an explicit pallas_lane wins, the legacy pallas_interpret bool
-        # is honored (True pins the interpreter), and the default is
-        # "auto" — compiled when the backend can lower Pallas, interpret
-        # fallback otherwise.  Only consulted by assembly="paop_pallas".
+        # which Pallas lane runs ("compiled" or "interpret"): an explicit
+        # pallas_lane wins, the legacy pallas_interpret bool is honored
+        # (True pins the interpreter), and the default "auto" follows the
+        # backend.  Only consulted by assembly="paop_pallas", which the
+        # compiled lane runs in float32 only.
         self.pallas_lane = resolve_lane(pallas_lane, interpret=pallas_interpret)
+        if assembly == "paop_pallas":
+            check_compiled_dtype(dtype, self.pallas_lane)
         self.shard_mesh = shard_mesh
 
         geom = quadrature_geometry(space.mesh, self.tables)

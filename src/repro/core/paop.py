@@ -16,7 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.contract import backward_grad_t, forward_grad
+from repro.core.contract import backward_grad_t, einsum, forward_grad
 from repro.core.voigt import VOIGT_INDEX, stress_voigt
 
 __all__ = ["paop_element", "paop_apply", "paop_apply_scenarios"]
@@ -32,7 +32,7 @@ def paop_element(x_e, lam_w, mu_w, jinv, B, G):
     # Forward: sum-factorized reference gradient (3c, 3m, qz, qy, qx).
     grad_ref = forward_grad(x_e, B, G)
     # Physical gradient d_j u_c = sum_m ghat[c, m] Jinv[m, j].
-    grad = jnp.einsum("cmzyx,mj->zyxcj", grad_ref, jinv)
+    grad = einsum("cmzyx,mj->zyxcj", grad_ref, jinv)
 
     # Pointwise structured Voigt stress (weighted): (qz, qy, qx, 6).
     sv = stress_voigt(grad, lam_w, mu_w)
@@ -46,7 +46,7 @@ def paop_element(x_e, lam_w, mu_w, jinv, B, G):
         ],
         axis=-2,
     )  # (qz, qy, qx, c, j)
-    q = jnp.einsum("zyxcj,mj->cmzyx", rows, jinv)
+    q = einsum("zyxcj,mj->cmzyx", rows, jinv)
     return backward_grad_t(q, B, G)
 
 

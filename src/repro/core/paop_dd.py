@@ -34,7 +34,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.basis import basis_tables
 from repro.core.geometry import MATERIALS_BEAM, make_quadrature_data
 from repro.core.paop import paop_apply
-from repro.distributed.sharding import shard_map
 from repro.fem.mesh import HexMesh
 from repro.fem.space import H1Space
 
@@ -167,7 +166,7 @@ class SlabDecomposition:
                 y3 = y3.at[:, 0, :, :].add(hi_y).at[:, -1, :, :].add(lo_y)
             return y3.reshape(1, -1, 3)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(self._shard_spec, self._shard_spec, self._shard_spec),

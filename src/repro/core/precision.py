@@ -52,7 +52,14 @@ The batched solver detects this per scenario (masked, exactly like
 per-scenario convergence) and the solve/serving layers re-solve only
 the affected rows under the ``f64`` policy — see
 :func:`repro.solvers.batched.bpcg_chunk` (stall counters) and
-``docs/PRECISION.md`` for the contract.
+``docs/PRECISION.md`` for the contract.  Where the ``f64`` policy cannot
+run (see :func:`policy_refusal`) there is no re-solve: a stalled row is
+reported unconverged and ``stalled``.
+
+On a TPU (:func:`emulates_f64`) the default policy is ``mixed`` and the
+``f64`` policy is refused: the chip has no float64 units, XLA emulates
+every f64 op, and the f64 V-cycle's step program for the paper's p=8
+beam did not finish compiling for a v5e in 23 minutes.
 """
 
 from __future__ import annotations
@@ -60,9 +67,18 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["PrecisionPolicy", "PRECISION_POLICIES", "resolve_precision"]
+__all__ = [
+    "PrecisionPolicy",
+    "PRECISION_POLICIES",
+    "resolve_precision",
+    "default_policy_name",
+    "emulates_f64",
+    "policy_refusal",
+    "check_policy",
+]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,15 +127,18 @@ def resolve_precision(
 
     ``precision`` is a policy name (``"f64"``, ``"f32"``, ``"mixed"``,
     ``"mixed-bf16"``), an explicit policy object, or None — meaning
-    "derive from the legacy ``dtype`` argument": f64 (or no dtype)
-    resolves to the ``f64`` policy, f32 to ``f32``, and any other
-    uniform dtype to an ad-hoc uniform policy named after it.  Passing
+    "derive from the legacy ``dtype`` argument": no dtype resolves to
+    the backend's default (:func:`default_policy_name`), f64 to the
+    ``f64`` policy, f32 to ``f32``, and any other uniform dtype to an
+    ad-hoc uniform policy named after it.  Passing
     both a policy and a conflicting ``dtype`` is an error — the policy
     is the single source of dtype truth."""
     if isinstance(precision, PrecisionPolicy):
         pol = precision
     elif precision is None:
-        if dtype is None or jnp.dtype(dtype) == jnp.dtype(jnp.float64):
+        if dtype is None:
+            return PRECISION_POLICIES[default_policy_name()]
+        if jnp.dtype(dtype) == jnp.dtype(jnp.float64):
             return PRECISION_POLICIES["f64"]
         for pol in PRECISION_POLICIES.values():
             if pol.uniform and jnp.dtype(pol.solve_dtype) == jnp.dtype(dtype):
@@ -141,3 +160,51 @@ def resolve_precision(
             f"other"
         )
     return pol
+
+
+def emulates_f64(backend: str | None = None) -> bool:
+    """True when ``backend`` (default: JAX's default backend) has no
+    float64 arithmetic and XLA emulates it — a TPU."""
+    b = backend if backend is not None else jax.default_backend()
+    return b == "tpu"
+
+
+def default_policy_name() -> str:
+    """The policy a solve runs when none is named: ``f64`` where the
+    backend computes in float64, ``mixed`` (f64 Krylov and stopping
+    test over an f32 V-cycle) where it emulates it."""
+    return "mixed" if emulates_f64() else "f64"
+
+
+def policy_refusal(
+    policy: PrecisionPolicy, assembly: str = "paop", lane: str = "interpret"
+) -> str | None:
+    """Why ``policy`` cannot run with ``assembly`` on Pallas ``lane``
+    and JAX's default backend, or None when it can.  Solvers and the
+    service refuse such a policy at construction and intake (see
+    :func:`check_policy`), and never fall back onto one."""
+    if emulates_f64() and jnp.dtype(policy.precond_dtype) == jnp.float64:
+        return (
+            f"precision policy {policy.name!r} runs its GMG V-cycle in "
+            f"float64, which a TPU only emulates: the f64 step program "
+            f"for the p=8 beam does not compile in a usable time.  Use "
+            f"precision='mixed' (f64 Krylov and stopping test over an "
+            f"f32 V-cycle; the default on a TPU) or 'f32'"
+        )
+    if assembly == "paop_pallas":
+        from repro.kernels.pa_elasticity.ops import compiled_dtype_refusal
+
+        for dt in (policy.solve_dtype, policy.precond_dtype):
+            msg = compiled_dtype_refusal(dt, lane)
+            if msg is not None:
+                return msg
+    return None
+
+
+def check_policy(
+    policy: PrecisionPolicy, assembly: str = "paop", lane: str = "interpret"
+) -> None:
+    """Raise ValueError with :func:`policy_refusal`'s reason, if any."""
+    msg = policy_refusal(policy, assembly, lane)
+    if msg is not None:
+        raise ValueError(msg)

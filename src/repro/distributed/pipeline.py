@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 
 __all__ = ["pipeline_apply", "split_stages", "bubble_fraction"]
 
@@ -97,7 +96,7 @@ def pipeline_apply(
         return out.reshape(x_full.shape)
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         staged,
         mesh=mesh,
         in_specs=(spec_p, P()),

@@ -49,22 +49,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map with a fallback for older jax, where it lives in
-    jax.experimental.shard_map and the replication-check kwarg is named
-    check_rep instead of check_vma."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
 __all__ = [
     "param_pspecs",
     "state_pspecs",

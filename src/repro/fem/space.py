@@ -100,18 +100,21 @@ class H1Space:
         return np.bincount(self.gather_ids.reshape(-1), minlength=self.nscalar)
 
     # -- E <-> L ---------------------------------------------------------------
+    # The mesh is a structured box, so the element restriction is dense
+    # data movement: along each axis element e owns nodes e*p .. e*p+p,
+    # i.e. a reshape of the first n*p nodes plus a strided slice for the
+    # shared last node.  No index gather or scatter-add: on a TPU those
+    # move one 3-wide row per (element, node) and dominated the operator
+    # apply at the paper's sizes.  ``gather_ids`` states the same map as
+    # indices (assembly and tests use it).
     def to_evec(self, u):
         """L-vector (nscalar, 3) -> E-vector (nelem, 3, D1D, D1D, D1D)."""
-        gid = jnp.asarray(self.gather_ids)
-        ue = u[gid]  # (nelem, D1D, D1D, D1D, 3)
-        return jnp.moveaxis(ue, -1, 1)
+        return _to_evec(u, self.mesh.shape, self.p)
 
     def scatter_add(self, ye):
         """E-vector (nelem, 3, D1D, D1D, D1D) -> L-vector (nscalar, 3) via
         G^T (sum of element contributions at shared nodes)."""
-        gid = jnp.asarray(self.gather_ids).reshape(-1)
-        yflat = jnp.moveaxis(ye, 1, -1).reshape(-1, VDIM)
-        return jax.ops.segment_sum(yflat, gid, num_segments=self.nscalar)
+        return _scatter_add(ye, self.mesh.shape, self.p)
 
     # -- node coordinates ------------------------------------------------------
     @functools.cached_property
@@ -204,3 +207,47 @@ class H1Space:
         # face axis collapsed; its flattened order matches outer(w_t1, w_t2).
         F[ids] = F_scale * face_w[:, None] * t[None, :]
         return F
+
+
+# Jitted so that an eager call is one executable, not one per reshape,
+# slice and concatenate (inside a traced program they inline).
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _to_evec(u, shape, p: int):
+    nx, ny, nz = shape
+    g = u.reshape(nz * p + 1, ny * p + 1, nx * p + 1, VDIM)
+    for axis, n in enumerate((nz, ny, nx)):
+        g = _split_axis(g, 2 * axis, n, p)
+    # (nz, Dz, ny, Dy, nx, Dx, 3) -> (e, 3, Dz, Dy, Dx)
+    g = g.transpose(0, 2, 4, 6, 1, 3, 5)
+    return g.reshape(nx * ny * nz, VDIM, p + 1, p + 1, p + 1)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _scatter_add(ye, shape, p: int):
+    nx, ny, nz = shape
+    g = ye.reshape(nz, ny, nx, VDIM, p + 1, p + 1, p + 1)
+    g = g.transpose(0, 4, 1, 5, 2, 6, 3)  # (nz, Dz, ny, Dy, nx, Dx, 3)
+    for axis, n in reversed(list(enumerate((nz, ny, nx)))):
+        g = _merge_axis(g, 2 * axis, n, p)
+    return g.reshape(-1, VDIM)
+
+
+def _split_axis(g, ax: int, n: int, p: int):
+    """n*p + 1 nodes along axis ``ax`` -> (n, p + 1) at axes (ax, ax + 1):
+    element e takes nodes e*p .. e*p + p."""
+    g = jnp.moveaxis(g, ax, 0)
+    body = g[: n * p].reshape((n, p) + g.shape[1:])
+    out = jnp.concatenate([body, g[p::p][:, None]], axis=1)
+    return jnp.moveaxis(out, (0, 1), (ax, ax + 1))
+
+
+def _merge_axis(g, ax: int, n: int, p: int):
+    """Transpose of :func:`_split_axis`: (n, p + 1) at axes (ax, ax + 1)
+    -> n*p + 1 nodes, summing the node neighbouring elements share."""
+    g = jnp.moveaxis(g, (ax, ax + 1), (0, 1))
+    body, last = g[:, :p], g[:, p]
+    body = body.at[1:, 0].add(last[:-1])
+    out = jnp.concatenate(
+        [body.reshape((n * p,) + body.shape[2:]), last[-1:]], axis=0
+    )
+    return jnp.moveaxis(out, 0, ax)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.basis import gll_nodes, lagrange_tables
+from repro.core.contract import einsum
 from repro.distributed.sharding import pin_scenario
 from repro.fem.space import H1Space
 
@@ -83,9 +84,9 @@ class Transfer:
         nxc, nyc, nzc = self.grid_c
         lead = u_c.shape[:-2]
         u = u_c.reshape(lead + (nzc, nyc, nxc, 3))
-        u = jnp.einsum("...zyxc,Xx->...zyXc", u, self.px)
-        u = jnp.einsum("...zyXc,Yy->...zYXc", u, self.py)
-        u = jnp.einsum("...zYXc,Zz->...ZYXc", u, self.pz)
+        u = einsum("...zyxc,Xx->...zyXc", u, self.px)
+        u = einsum("...zyXc,Yy->...zYXc", u, self.py)
+        u = einsum("...zYXc,Zz->...ZYXc", u, self.pz)
         return self._pin(u.reshape(lead + (-1, 3)))
 
     def restrict(self, r_f):
@@ -93,9 +94,9 @@ class Transfer:
         nxf, nyf, nzf = self.grid_f
         lead = r_f.shape[:-2]
         r = r_f.reshape(lead + (nzf, nyf, nxf, 3))
-        r = jnp.einsum("...ZYXc,Zz->...zYXc", r, self.pz)
-        r = jnp.einsum("...zYXc,Yy->...zyXc", r, self.py)
-        r = jnp.einsum("...zyXc,Xx->...zyxc", r, self.px)
+        r = einsum("...ZYXc,Zz->...zYXc", r, self.pz)
+        r = einsum("...zYXc,Yy->...zyXc", r, self.py)
+        r = einsum("...zyXc,Xx->...zyxc", r, self.px)
         return self._pin(r.reshape(lead + (-1, 3)))
 
 

@@ -32,14 +32,16 @@ CPU design decisions map as follows:
   reconstructs rows of sigma.J^{-T} through the symmetric index map.
 
 Lanes: `interpret=True` runs the Pallas interpreter (any backend, used
-for CPU CI); `interpret=False` is the *compiled* lane (TPU Mosaic /
-GPU Triton).  Lane selection with automatic fallback lives in
-`ops.resolve_lane`; this module takes the already-resolved boolean.
+for CPU CI); `interpret=False` is the *compiled* lane (TPU Mosaic).
+Lane selection lives in `ops.resolve_lane`; this module takes the
+already-resolved boolean.  Mosaic has no float64, so the compiled lane
+runs f32 (and narrower) only; `ops.pa_elasticity` refuses f64 there.
 
 The kernel assumes affine geometry with a mesh-constant J^{-1} (uniform
 box; the general per-element-affine case is handled by the pure-JAX PAop
-path).  Validated against `ref.paop_ref` across p in 1..8 and dtypes,
-and compiled-vs-interpret (see tests/test_pa_elasticity_kernel.py).
+path).  Validated against `ref.paop_ref` across p in 1..8 and dtypes
+(tests/test_pa_elasticity_kernel.py); tests/test_tpu_compile.py compiles
+it for a v5e.
 """
 
 from __future__ import annotations
@@ -49,37 +51,51 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["pa_elasticity_pallas"]
+from repro.core.contract import einsum
+
+__all__ = ["pa_elasticity_pallas", "VMEM_LIMIT_BYTES"]
+
+# The scoped VMEM Mosaic may use per grid step: half of a v5e
+# TensorCore's 128 MiB, well above the 16 MiB default scoped limit that
+# p=8 needs to exceed (about 21 MiB at the 128-element floor).
+# ``ops.block_workingset_bytes`` picks the block; a limit set to that
+# estimate exactly would leave no room for the compiler's own rounding
+# (p=8 overran a 21.09 MiB limit by 0.1 MiB).
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 # --------------------------------------------------------------------------
 # Element-last contraction helpers. Shapes: (..., axis_dim, EB); tables
-# (Q1D, D1D). Each is one MXU matmul of shape (Q1D, D1D) x (D1D, N).
+# (Q1D, D1D). Each is one MXU matmul of shape (Q1D, D1D) x (D1D, N), at
+# full f32 precision (``repro.core.contract.einsum``): the MXU's default
+# single bf16 pass would leave the operator ~1e-3 off, which the outer
+# Krylov iteration cannot hide.
 # --------------------------------------------------------------------------
 def _cx(t, table):
     # contract ix: (..., z, y, x, e) . (q, x) -> (..., z, y, q, e)
-    return jnp.einsum("...zyxe,qx->...zyqe", t, table)
+    return einsum("...zyxe,qx->...zyqe", t, table)
 
 
 def _cy(t, table):
-    return jnp.einsum("...zyqe,ry->...zrqe", t, table)
+    return einsum("...zyqe,ry->...zrqe", t, table)
 
 
 def _cz(t, table):
-    return jnp.einsum("...zrqe,sz->...srqe", t, table)
+    return einsum("...zrqe,sz->...srqe", t, table)
 
 
 def _cx_t(t, table):
-    return jnp.einsum("...zyqe,qx->...zyxe", t, table)
+    return einsum("...zyqe,qx->...zyxe", t, table)
 
 
 def _cy_t(t, table):
-    return jnp.einsum("...zrqe,ry->...zyqe", t, table)
+    return einsum("...zrqe,ry->...zyqe", t, table)
 
 
 def _cz_t(t, table):
-    return jnp.einsum("...srqe,sz->...zrqe", t, table)
+    return einsum("...srqe,sz->...zrqe", t, table)
 
 
 def _kernel(x_ref, lam_ref, mu_ref, jinv_ref, b_ref, g_ref, y_ref):
@@ -87,7 +103,7 @@ def _kernel(x_ref, lam_ref, mu_ref, jinv_ref, b_ref, g_ref, y_ref):
 
     x_ref:   (3, D1D, D1D, D1D, EB)   VMEM
     lam_ref: (Q1D, Q1D, Q1D, EB)      VMEM  (mu_ref likewise)
-    jinv_ref:(3, 3)                   constant per mesh (affine)
+    jinv_ref:(3, 3)                   SMEM, constant per mesh (affine)
     b_ref:   (Q1D, D1D), g_ref: (Q1D, D1D)
     y_ref:   (3, D1D, D1D, D1D, EB)   VMEM
 
@@ -100,7 +116,8 @@ def _kernel(x_ref, lam_ref, mu_ref, jinv_ref, b_ref, g_ref, y_ref):
     """
     B = b_ref[...]
     G = g_ref[...]
-    jinv = jinv_ref[...]
+    # J^{-1} entries are scalars: read them from SMEM, not a VMEM vector.
+    jinv = [[jinv_ref[m, j] for j in range(3)] for m in range(3)]
     lam_w = lam_ref[...]
     mu_w = mu_ref[...]
 
@@ -121,7 +138,7 @@ def _kernel(x_ref, lam_ref, mu_ref, jinv_ref, b_ref, g_ref, y_ref):
         g2 = _cz(_cy(u, B), G)
         # physical row: d_j u_c = sum_m ghat[c, m] Jinv[m, j]
         for j in range(3):
-            grad_cj = g0 * jinv[0, j] + g1 * jinv[1, j] + g2 * jinv[2, j]
+            grad_cj = g0 * jinv[0][j] + g1 * jinv[1][j] + g2 * jinv[2][j]
             if j == c:
                 diag[c] = grad_cj
             else:
@@ -152,9 +169,9 @@ def _kernel(x_ref, lam_ref, mu_ref, jinv_ref, b_ref, g_ref, y_ref):
     for c in range(3):
         # q_m = sum_j sigma[c, j] Jinv[m, j]   (3 pullback rows live)
         q = [
-            sigma(c, 0) * jinv[m, 0]
-            + sigma(c, 1) * jinv[m, 1]
-            + sigma(c, 2) * jinv[m, 2]
+            sigma(c, 0) * jinv[m][0]
+            + sigma(c, 1) * jinv[m][1]
+            + sigma(c, 2) * jinv[m][2]
             for m in range(3)
         ]
         # transpose sweeps: G along the derivative direction m, B elsewhere
@@ -164,46 +181,46 @@ def _kernel(x_ref, lam_ref, mu_ref, jinv_ref, b_ref, g_ref, y_ref):
         y_ref[c] = y_c
 
 
-@functools.partial(
-    jax.jit, static_argnames=("d1d", "q1d", "eb", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("d1d", "q1d", "eb", "interpret"))
 def pa_elasticity_pallas(x_e, lam_w, mu_w, jinv, B, G, *, d1d, q1d, eb, interpret):
     """Apply the fused PAop kernel.
 
     x_e: (3, D1D, D1D, D1D, NE) element-last layout, NE a multiple of eb.
     lam_w/mu_w: (Q1D, Q1D, Q1D, NE); jinv: (3, 3); B/G: (Q1D, D1D).
-    ``interpret=False`` is the compiled lane (native Pallas lowering);
-    callers go through ``ops.pa_elasticity``, which resolves the lane
-    against backend capability first.
+    ``interpret=False`` is the compiled lane (Mosaic); callers go through
+    ``ops.pa_elasticity``, which resolves the lane and picks ``eb`` first.
     """
     ne = x_e.shape[-1]
-    assert ne % eb == 0, (ne, eb)
+    if ne % eb:
+        raise ValueError(f"element count {ne} is not a multiple of eb={eb}")
     grid = (ne // eb,)
 
+    # Block indices are int32 for Mosaic; a bare Python 0 would trace
+    # as int64 whenever jax_enable_x64 is on (as the solver entry points
+    # set it), which Mosaic refuses to lower.
     def e_idx(i):
-        return (0, 0, 0, 0, i)
+        z = jnp.int32(0)
+        return (z, z, z, z, i)
 
     def q_idx(i):
-        return (0, 0, 0, i)
+        z = jnp.int32(0)
+        return (z, z, z, i)
 
     def full(i):
-        return (0, 0)
+        z = jnp.int32(0)
+        return (z, z)
 
     kwargs = {}
     if not interpret:
-        # Compiled lane: element blocks are independent, so the grid is
-        # free to execute in any order (enables Mosaic to overlap the
-        # next block's DMA with this block's compute).
-        try:
-            from jax.experimental.pallas import tpu as pltpu
+        # Element blocks are independent, so the grid axis is "parallel":
+        # Mosaic may run the steps in any order (and split them across
+        # TensorCores where a chip has more than one).
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        )
 
-            kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-                dimension_semantics=("arbitrary",)
-            )
-        except (ImportError, AttributeError):  # pragma: no cover
-            pass  # non-TPU compiled lowering (e.g. Triton) needs none
-
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct(x_e.shape, x_e.dtype),
         grid=grid,
@@ -211,7 +228,7 @@ def pa_elasticity_pallas(x_e, lam_w, mu_w, jinv, B, G, *, d1d, q1d, eb, interpre
             pl.BlockSpec((3, d1d, d1d, d1d, eb), e_idx),
             pl.BlockSpec((q1d, q1d, q1d, eb), q_idx),
             pl.BlockSpec((q1d, q1d, q1d, eb), q_idx),
-            pl.BlockSpec((3, 3), full),
+            pl.BlockSpec((3, 3), full, memory_space=pltpu.SMEM),
             pl.BlockSpec((q1d, d1d), full),
             pl.BlockSpec((q1d, d1d), full),
         ],
@@ -219,4 +236,3 @@ def pa_elasticity_pallas(x_e, lam_w, mu_w, jinv, B, G, *, d1d, q1d, eb, interpre
         interpret=interpret,
         **kwargs,
     )(x_e, lam_w, mu_w, jinv, B, G)
-    return out

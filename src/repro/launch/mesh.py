@@ -17,26 +17,13 @@ Axis roles (see repro.distributed.sharding):
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; older jax uses plain meshes.
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
     "make_local_mesh",
-    "axis_type_kwargs",
     "MESH_AXES",
 ]
-
-
-def axis_type_kwargs(n: int) -> dict:
-    """``axis_types=(Auto,) * n`` for jax.make_mesh where supported, {}
-    on older jax (which only has implicitly-auto meshes)."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n}
 
 MESH_AXES = {
     False: ("data", "model"),
@@ -47,7 +34,7 @@ MESH_AXES = {
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model_parallel: int | None = None):
@@ -57,5 +44,5 @@ def make_local_mesh(model_parallel: int | None = None):
     while n % mp:
         mp //= 2
     return jax.make_mesh(
-        (n // mp, mp), ("data", "model"), **axis_type_kwargs(2)
+        (n // mp, mp), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )

@@ -164,19 +164,20 @@ def main() -> None:
     ap.add_argument("--pallas-lane", default="auto",
                     choices=["auto", "compiled", "interpret"],
                     help="Pallas kernel lane for paop_pallas assembly: "
-                         "compiled (native lowering) with automatic "
-                         "interpret fallback on backends that cannot "
-                         "lower Pallas (the service reports the lane "
-                         "that actually ran)")
-    ap.add_argument("--precision", default="f64",
+                         "auto is compiled (Mosaic, f32 policies only) "
+                         "on a TPU and interpret elsewhere; compiled "
+                         "off a TPU is an error")
+    ap.add_argument("--precision", default=None,
                     choices=["f64", "f32", "mixed", "mixed-bf16"],
                     help="service-default precision policy (requests may "
                          "still name their own): f64, f32 (uniform), or "
                          "mixed / mixed-bf16 (f64 outer Krylov over a "
-                         "reduced-precision V-cycle).  Reduced policies "
-                         "auto-fall-back stagnated rows to f64 — the "
-                         "report's prec column shows the policy that "
-                         "produced each answer, * marks a fallback")
+                         "reduced-precision V-cycle).  Default f64, or "
+                         "mixed on a TPU, which refuses f64.  Reduced "
+                         "policies auto-fall-back stagnated rows to f64 "
+                         "where it runs — the report's prec column shows "
+                         "the policy that produced each answer, * marks "
+                         "a fallback")
     ap.add_argument("--repeat", type=int, default=1,
                     help="re-run the workload to demonstrate cache hits")
     ap.add_argument("--continuous", action="store_true",
@@ -240,6 +241,9 @@ def main() -> None:
                          "locally executed continuous steps (after the "
                          "checkpoint hook) — fault-injection test hook")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.checkpoint_dir and not args.continuous:
         ap.error("--checkpoint-dir requires --continuous (the "
                  "generational path holds no resumable in-flight state)")

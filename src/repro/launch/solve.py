@@ -24,9 +24,11 @@ import numpy as np
 
 from repro.core.geometry import MATERIALS_BEAM
 from repro.core.operators import ElasticityOperator
-from repro.core.precision import resolve_precision
+from repro.core.precision import check_policy, resolve_precision
 from repro.fem.bc import eliminate_rhs
 from repro.fem.mesh import beam_hex
+from repro.kernels.pa_elasticity.ops import resolve_lane
+from repro.launch.compile_cache import use_compile_cache
 from repro.solvers.cg import pcg
 from repro.solvers.gmg import build_hierarchy
 
@@ -71,8 +73,12 @@ def solve_beam(
     ``precond_dtype`` while the outer PCG — operator apply, residual
     norms, tolerance test — runs at ``solve_dtype``, with casts only at
     the preconditioner boundary.  The legacy uniform ``dtype`` argument
-    still works (f64 default)."""
+    still works.  With neither, the backend's default policy runs (f64;
+    mixed on a TPU)."""
     policy = resolve_precision(precision, dtype)
+    check_policy(
+        policy, assembly, resolve_lane(pallas_lane, interpret=pallas_interpret)
+    )
     coarse_mesh = coarse_mesh if coarse_mesh is not None else beam_hex()
     materials = materials if materials is not None else MATERIALS_BEAM
     t0 = time.perf_counter()
@@ -149,17 +155,19 @@ def main() -> None:
     # The f64 tiers of every policy need x64 enabled; without it jax
     # silently truncates to f32 and the residual accounting lies.
     jax.config.update("jax_enable_x64", True)
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--p", type=int, default=2)
     ap.add_argument("--refine", type=int, default=1)
     ap.add_argument("--assembly", default="paop")
     ap.add_argument("--coarse", default="cholesky")
     ap.add_argument("--rel-tol", type=float, default=1e-6)
-    ap.add_argument("--precision", default="f64",
+    ap.add_argument("--precision", default=None,
                     choices=["f64", "f32", "mixed", "mixed-bf16"],
                     help="precision policy: uniform f64/f32, or mixed / "
                          "mixed-bf16 (f64 outer PCG + residual test over "
-                         "a reduced-precision V-cycle)")
+                         "a reduced-precision V-cycle).  Default f64, or "
+                         "mixed on a TPU, which refuses f64")
     args = ap.parse_args()
 
     rep = solve_beam(

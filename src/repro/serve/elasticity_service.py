@@ -98,7 +98,13 @@ from repro.core.geometry import (
     check_material_dict,
     check_material_fields,
 )
-from repro.core.precision import PrecisionPolicy, resolve_precision
+from repro.core.precision import (
+    PRECISION_POLICIES,
+    PrecisionPolicy,
+    check_policy,
+    policy_refusal,
+    resolve_precision,
+)
 from repro.distributed.sharding import scenario_row_devices
 from repro.fem.mesh import HexMesh, beam_hex
 from repro.serve.chunk_policy import (
@@ -205,7 +211,9 @@ class SolveRequest:
     share a compiled program — and is recorded on the report.  Rows a
     reduced-precision flight flags as stagnated are automatically
     re-queued (same ticket, original submit time) onto the ``f64``
-    path; their reports carry ``fallback=True``."""
+    path; their reports carry ``fallback=True``.  Where ``f64`` cannot
+    run (``ElasticityService.fallback_precision`` is None) they retire
+    unconverged with ``stalled=True``."""
 
     p: int = 2
     refine: int = 1
@@ -273,6 +281,10 @@ class SolveReport:
     # service re-solved on the f64 path (precision then reads "f64").
     precision: str = "f64"
     fallback: bool = False
+    # A reduced-precision row that stagnated (or failed the true-residual
+    # audit) and had no f64 path to fall back on (a TPU, the compiled
+    # Pallas lane): reported unconverged, never re-solved.
+    stalled: bool = False
     # The continuous engine's submit() ticket this report answers (-1 on
     # the generational path, which returns reports positionally).  The
     # stable join key for crash/restore differentials: a resumed
@@ -392,14 +404,23 @@ class ElasticityService:
         self.maxiter = maxiter
         # Pallas lane for every solver this service builds, resolved at
         # construction ("compiled" or "interpret"; "auto" — the default
-        # — picks compiled when the backend can lower Pallas and falls
-        # back to interpret otherwise).  ``pallas_interpret`` is the
-        # legacy bool spelling: True pins the interpreter.  The resolved
-        # value is the service's report of which lane actually runs.
+        # — follows the backend).  ``pallas_interpret`` is the legacy
+        # bool spelling: True pins the interpreter.  The resolved value
+        # is the service's report of which lane runs.
         from repro.kernels.pa_elasticity.ops import resolve_lane
 
         self.pallas_lane = resolve_lane(pallas_lane, interpret=pallas_interpret)
         self.pallas_interpret = self.pallas_lane == "interpret"
+        self._check_policy(self.precision)
+        # Policy a stalled reduced-precision row is re-solved under, or
+        # None where ``f64`` cannot run (a TPU, the compiled Pallas
+        # lane): such a row retires unconverged with ``stalled=True``.
+        self.fallback_precision = (
+            None
+            if policy_refusal(PRECISION_POLICIES["f64"], assembly,
+                              self.pallas_lane)
+            else "f64"
+        )
         self.chunk_iters = chunk_iters
         # Chunk scheduling policy for the continuous path.  The old
         # ``chunk_iters < 1`` check generalizes to the policy-bound
@@ -529,6 +550,11 @@ class ElasticityService:
             return self.precision
         return resolve_precision(req.precision)
 
+    def _check_policy(self, policy: PrecisionPolicy) -> None:
+        """Refuse, before any batch state exists, a policy whose solvers
+        could not be built here (see ``precision.policy_refusal``)."""
+        check_policy(policy, self.assembly, self.pallas_lane)
+
     def group_key(self, req: SolveRequest) -> tuple:
         """Flight/compile-cache key.  Leads with (p, refine, shape) but
         also covers everything else a compiled program is specialized
@@ -590,7 +616,9 @@ class ElasticityService:
                         f"{mesh.shape})"
                     ),
                 )
-        self._policy_for(request)  # unknown precision names fail at intake
+        # unknown precision names, and policies this service's kernel
+        # lane cannot run, fail at intake
+        self._check_policy(self._policy_for(request))
         ticket = self._next_ticket
         self._next_ticket += 1
         self._t_submit[ticket] = self.clock()
@@ -805,15 +833,18 @@ class ElasticityService:
             slot = flight.slots[i]
             req = slot.request
             converged = bool(nom[i] <= thr[i])
-            if reduced and bool(stalled[i]) and not converged:
+            row_stalled = reduced and bool(stalled[i]) and not converged
+            if row_stalled and self.fallback_precision is not None:
                 # Stagnated under the reduced policy (or failed the true-
                 # residual audit): re-queue the SAME ticket onto the f64
                 # path with its original submit time, so the fallback is
                 # a scheduling event, not a failed report.  The eventual
-                # f64 report carries ``fallback=True``.
+                # f64 report carries ``fallback=True``.  With no f64
+                # path the row retires below, unconverged and stalled.
                 self._queue.append(
                     (slot.ticket,
-                     dataclasses.replace(req, precision="f64"))
+                     dataclasses.replace(
+                         req, precision=self.fallback_precision))
                 )
                 self._t_submit[slot.ticket] = slot.t_submit
                 self._fallback_tickets.add(slot.ticket)
@@ -872,6 +903,7 @@ class ElasticityService:
                 padded_rows=flight.bucket,
                 precision=flight.key[-1],
                 fallback=fell_back,
+                stalled=row_stalled,
                 ticket=slot.ticket,
                 x=np.asarray(flight.state.x[i])
                 if req.keep_solution
@@ -1284,6 +1316,7 @@ class ElasticityService:
         fin = np.asarray(res.final_norm)
         ini = np.asarray(res.initial_norm)
         fell_back = np.asarray(res.fallback)
+        stalled = np.asarray(res.stalled) & ~conv
         ndof = solver.fine_space.ndof
         out = []
         # Padding rows (s >= n_real) are internal and never reported.
@@ -1308,6 +1341,7 @@ class ElasticityService:
                     padded_rows=n_real + n_pad,
                     precision=solver.precision.name,
                     fallback=bool(fell_back[s]),
+                    stalled=bool(stalled[s]),
                     x=np.asarray(x[s]) if req.keep_solution else None,
                 )
             )

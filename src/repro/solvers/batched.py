@@ -71,7 +71,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.operators import DEFER_MATERIALS, ElasticityOperator
-from repro.core.precision import PrecisionPolicy, resolve_precision
+from repro.core.precision import (
+    PRECISION_POLICIES,
+    PrecisionPolicy,
+    check_policy,
+    policy_refusal,
+    resolve_precision,
+)
 from repro.kernels.pa_elasticity.ops import resolve_lane
 from repro.distributed.sharding import (
     device_put_scenario,
@@ -471,9 +477,17 @@ class BatchedGMGSolver:
         self._traction_face = traction_face
         # Pallas lane, resolved ONCE here so every level operator runs
         # the same lane and ``self.pallas_lane`` reports what actually
-        # runs ("compiled" or "interpret"; auto falls back to interpret
-        # on backends that cannot lower Pallas natively).
+        # runs ("compiled" or "interpret"; auto is compiled on a TPU and
+        # interpret elsewhere).
         self.pallas_lane = resolve_lane(pallas_lane, interpret=pallas_interpret)
+        check_policy(self.precision, assembly, self.pallas_lane)
+        # Whether stalled rows can be re-solved under ``f64`` here (not
+        # on a TPU, nor on the compiled Pallas lane); when not, they
+        # stay unconverged and ``stalled`` in the result.
+        self.can_fall_back = (
+            policy_refusal(PRECISION_POLICIES["f64"], assembly,
+                           self.pallas_lane) is None
+        )
         # Scenario-axis device mesh (None = single-device).  An int is
         # shorthand for "shard over the first n devices".
         self.mesh, self.n_shards = normalize_scenario_mesh(mesh)
@@ -1207,7 +1221,9 @@ class BatchedGMGSolver:
         them, ``iterations`` counts the total work (reduced + f64
         passes), and the merged result is promoted to f64 (only
         observable for the uniform ``f32`` policy; mixed policies
-        already solve in f64)."""
+        already solve in f64).  Where ``f64`` cannot run
+        (``can_fall_back`` is False) flagged rows stay unconverged, with
+        ``stalled`` set."""
         materials, tractions, rel_tol, s = self.pad_scenarios(
             materials, tractions, rel_tol
         )
@@ -1225,7 +1241,7 @@ class BatchedGMGSolver:
                     for fld in dataclasses.fields(BPCGResult)
                 }
             )
-        if self.precision.reduced:
+        if self.precision.reduced and self.can_fall_back:
             need = np.asarray(res.stalled) & ~np.asarray(res.converged)
             if need.any():
                 rows = np.nonzero(need)[0]

@@ -120,8 +120,8 @@ def build_hierarchy(
 ) -> GMGPreconditioner:
     """Build the paper's GMG preconditioner for the beam benchmark.
 
-    ``pallas_lane`` ("auto"/"compiled"/"interpret", default auto with
-    interpret fallback) selects the Pallas lane for every
+    ``pallas_lane`` ("auto"/"compiled"/"interpret", default auto: compiled
+    on a TPU, interpret elsewhere) selects the Pallas lane for every
     ``paop_pallas`` level; the legacy ``pallas_interpret`` bool is
     honored when no lane is given."""
     spaces = hierarchy_spaces(coarse_mesh, n_h_refine, p_target)

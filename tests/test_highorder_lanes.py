@@ -1,24 +1,22 @@
 """High-order (p=4, p=6) lane differentials.
 
-The Pallas lane — compiled vs interpret, with automatic fallback — is
-an implementation detail of the ``paop_pallas`` assembly: it must never
-change what a solve computes.  These tests lock that down at the three
-levels users touch:
+The fused kernel (``paop_pallas``) must compute what the einsum
+``paop`` assembly computes.  These tests lock that down on the
+interpreter lane at the three levels users touch:
 
-* solver (``BatchedGMGSolver.solve``): compiled-lane and
-  interpret-lane runs produce identical iteration counts and solutions,
-  and both agree with the einsum ``paop`` reference assembly;
+* solver (``BatchedGMGSolver.solve``): identical iteration counts and
+  matching solutions against the ``paop`` reference assembly;
 * service (``ElasticityService``): the batched/generational path
-  reports the same outcome regardless of lane, and
-  ``service.pallas_lane`` reports the lane that actually runs;
-* sharded (8 virtual devices): the lane differential survives
-  scenario-axis sharding.
+  reports the same outcome for both assemblies, and
+  ``service.pallas_lane`` reports the lane that runs;
+* sharded (8 virtual devices): the kernel survives scenario-axis
+  sharding.
 
-On backends without native Pallas lowering (the CPU CI containers) the
-compiled request falls back to the interpreter, so the two lanes are
-bitwise identical — exercising exactly the fallback path a TPU-trained
-artifact relies on when replayed on CPU.  Lane *resolution* plumbing is
-covered by fast tests via the monkeypatched capability cache.
+The compiled lane exists only on a TPU: elsewhere a compiled request
+raises (asserted here), ``tests/test_tpu_compile.py`` compiles it for a
+described v5e, and ``chip_smoke.py`` checks its numbers on the chip.
+Lane *resolution* plumbing is covered by fast tests that steer
+``ops.backend_supports_compiled``.
 """
 
 import jax
@@ -77,23 +75,28 @@ def _assert_same_solve(res, ref, context, *, exact=False):
 
 def test_lane_plumbing_solver_and_service(monkeypatch):
     """The lane resolves ONCE at construction in every layer, and the
-    stored value is the lane that actually runs, not the request."""
-    backend = jax.default_backend()
-
-    monkeypatch.setitem(ops._SUPPORT_CACHE, backend, False)
+    stored value is the lane that runs.  A compiled request on a backend
+    that cannot lower Pallas raises; on a TPU the compiled lane takes
+    f32 policies only."""
+    monkeypatch.setattr(ops, "backend_supports_compiled", lambda b=None: False)
     solver = BatchedGMGSolver(beam_hex(), 0, 1, assembly="paop_pallas")
-    assert solver.pallas_lane == "interpret"  # auto fell back
-    svc = ElasticityService(assembly="paop_pallas", pallas_lane="compiled")
-    assert svc.pallas_lane == "interpret"  # request honestly downgraded
-    assert svc.pallas_interpret is True
+    assert solver.pallas_lane == "interpret"  # auto follows the backend
+    with pytest.raises(ValueError, match="needs a TPU backend"):
+        ElasticityService(assembly="paop_pallas", pallas_lane="compiled")
 
-    monkeypatch.setitem(ops._SUPPORT_CACHE, backend, True)
-    solver = BatchedGMGSolver(beam_hex(), 0, 1, assembly="paop_pallas")
+    monkeypatch.setattr(ops, "backend_supports_compiled", lambda b=None: True)
+    solver = BatchedGMGSolver(
+        beam_hex(), 0, 1, assembly="paop_pallas", precision="f32"
+    )
     assert solver.pallas_lane == "compiled"
     assert solver._base_ops[-1].pallas_lane == "compiled"
-    svc = ElasticityService(assembly="paop_pallas")
+    svc = ElasticityService(assembly="paop_pallas", precision="f32")
     assert svc.pallas_lane == "compiled"
     assert svc.pallas_interpret is False
+    with pytest.raises(ValueError, match="precision='f32'"):
+        ElasticityService(assembly="paop_pallas")  # f64 default policy
+    with pytest.raises(ValueError, match="precision='f32'"):
+        svc.submit(SolveRequest(p=2, refine=0, precision="mixed"))
     # the legacy bool still pins the interpreter even when capable
     svc = ElasticityService(assembly="paop_pallas", pallas_interpret=True)
     assert svc.pallas_lane == "interpret"
@@ -103,16 +106,15 @@ def test_build_hierarchy_threads_lane(monkeypatch):
     """Unlike the deferred-materials batched solver, build_hierarchy
     APPLIES the operator at construction (smoother power iterations),
     so it must already run the resolved lane — a compiled request on an
-    incapable backend is recorded (and executed) as interpret on every
-    pallas level."""
+    incapable backend raises before any level is applied, and the
+    interpreter pin reaches every pallas level."""
     from repro.solvers.gmg import build_hierarchy
 
-    backend = jax.default_backend()
-    monkeypatch.setitem(ops._SUPPORT_CACHE, backend, False)
-    gmg = build_hierarchy(
-        beam_hex(), 0, 2, assembly="paop_pallas", pallas_lane="compiled"
-    )
-    assert gmg.fine.operator.pallas_lane == "interpret"
+    monkeypatch.setattr(ops, "backend_supports_compiled", lambda b=None: False)
+    with pytest.raises(ValueError, match="needs a TPU backend"):
+        build_hierarchy(
+            beam_hex(), 0, 2, assembly="paop_pallas", pallas_lane="compiled"
+        )
     gmg = build_hierarchy(
         beam_hex(), 0, 2, assembly="paop_pallas", pallas_interpret=True
     )
@@ -125,23 +127,17 @@ def test_build_hierarchy_threads_lane(monkeypatch):
 @pytest.mark.slow
 @pytest.mark.parametrize("p", [4, 6])
 def test_solver_lane_differential(p):
-    """compiled vs interpret vs the einsum paop reference at high
-    order: identical iteration counts, matching solutions."""
+    """The kernel vs the einsum paop reference at high order: identical
+    iteration counts, matching solutions.  A compiled request raises off
+    a TPU instead of interpreting."""
     si, ri = _solve(p, "paop_pallas", "interpret")
-    sc, rc = _solve(p, "paop_pallas", "compiled")
     _, ref = _solve(p, "paop")
     assert si.pallas_lane == "interpret"
-    assert sc.pallas_lane == (
-        "compiled" if ops.backend_supports_compiled() else "interpret"
-    )
-    # lanes of the SAME kernel: bitwise when compiled fell back
-    _assert_same_solve(
-        rc, ri, f"p={p} compiled vs interpret",
-        exact=sc.pallas_lane == "interpret",
-    )
-    # kernel vs einsum reference assembly
     _assert_same_solve(ri, ref, f"p={p} paop_pallas vs paop")
     assert bool(np.all(np.asarray(ref.converged)))
+    if not ops.backend_supports_compiled():
+        with pytest.raises(ValueError, match="needs a TPU backend"):
+            _solve(p, "paop_pallas", "compiled")
 
 
 # -- slow: service differential ----------------------------------------------
@@ -149,25 +145,22 @@ def test_solver_lane_differential(p):
 
 @pytest.mark.slow
 def test_service_lane_differential():
-    """The generational service path reports identical outcomes per
-    lane at p=4, and each report's solver ran the resolved lane."""
+    """The generational service path reports the same outcomes for the
+    kernel (interpreter lane) and the einsum assembly at p=4, and the
+    service reports the lane its solvers ran."""
     reports = {}
-    for lane in ("interpret", "compiled"):
+    for assembly in ("paop_pallas", "paop"):
         svc = ElasticityService(
-            assembly="paop_pallas", pallas_lane=lane, maxiter=MAXITER
+            assembly=assembly, pallas_lane="interpret", maxiter=MAXITER
         )
         reqs = [
             SolveRequest(p=4, refine=0, materials=m, traction=tuple(t),
                          rel_tol=1e-8, keep_solution=True)
             for m, t in zip(MATS, TRACTIONS)
         ]
-        reports[lane] = svc.solve(reqs)
-        assert svc.pallas_lane == (
-            lane if lane == "interpret"
-            else ("compiled" if ops.backend_supports_compiled()
-                  else "interpret")
-        )
-    for a, b in zip(reports["interpret"], reports["compiled"]):
+        reports[assembly] = svc.solve(reqs)
+        assert svc.pallas_lane == "interpret"
+    for a, b in zip(reports["paop_pallas"], reports["paop"]):
         assert a.iterations == b.iterations
         assert a.converged and b.converged
         np.testing.assert_allclose(
@@ -183,9 +176,8 @@ def test_service_lane_differential():
 @pytest.mark.slow
 @pytest.mark.multidevice
 def test_sharded_lane_differential():
-    """Scenario-sharding over 8 virtual devices composes with the lane
-    machinery: the sharded compiled-lane solve reproduces the unsharded
-    interpret-lane solve at p=4."""
+    """Scenario-sharding over 8 virtual devices composes with the fused
+    kernel: the sharded solve reproduces the unsharded one at p=4."""
     if jax.device_count() < 8:
         pytest.skip(f"needs 8 devices, have {jax.device_count()}")
     mats, tr, tol = [], [], []
@@ -195,8 +187,8 @@ def test_sharded_lane_differential():
         tol.append(1e-8)
     tr, tol = np.asarray(tr), np.asarray(tol)
     _, ref = _solve(4, "paop_pallas", "interpret", mats=mats, tr=tr, tol=tol)
-    ss, rs = _solve(4, "paop_pallas", "compiled", mesh=scenario_mesh(8),
+    ss, rs = _solve(4, "paop_pallas", "auto", mesh=scenario_mesh(8),
                     mats=mats, tr=tr, tol=tol)
     assert ss.n_shards == 8
     # sharded partitioning fuses differently: ~ulp, not bitwise
-    _assert_same_solve(rs, ref, "sharded compiled vs unsharded interpret")
+    _assert_same_solve(rs, ref, "sharded vs unsharded paop_pallas")

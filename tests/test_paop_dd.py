@@ -6,9 +6,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core.operators import ElasticityOperator
-from repro.launch.mesh import axis_type_kwargs
 from repro.core.paop_dd import SlabDecomposition, choose_grid
 from repro.fem.mesh import beam_hex
 from repro.fem.space import H1Space
@@ -16,7 +16,7 @@ from repro.fem.space import H1Space
 
 def _mesh_1d():
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("shard",), **axis_type_kwargs(1))
+    return jax.make_mesh((n,), ("shard",), axis_types=(AxisType.Auto,))
 
 
 def test_choose_grid():

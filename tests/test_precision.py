@@ -1,7 +1,8 @@
 """Precision-policy suite: dtype invariance of the prep/state pytrees,
 f32/mixed-vs-f64 differentials, the honest mixed-tolerance acceptance
 run, engineered stagnation -> automatic f64 fallback (solver and
-service level), and the policy axis of the compile cache.
+service level) or, where f64 cannot run (a TPU, steered here), a
+stalled unconverged report, and the policy axis of the compile cache.
 
 Run alone by the ``precision`` CI lane
 (``pytest -q tests/test_precision.py -m "not slow"``); the slow-marked
@@ -15,11 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import precision as precision_mod
 from repro.core.precision import (
     PRECISION_POLICIES,
     PrecisionPolicy,
     resolve_precision,
 )
+from repro.kernels.pa_elasticity import ops
 from repro.fem.mesh import beam_hex
 from repro.launch.solve import solve_beam
 from repro.serve.elasticity_service import ElasticityService, SolveRequest
@@ -307,6 +310,79 @@ def test_generational_path_reports_fallback():
     assert [r.fallback for r in reports] == [False, True]
     assert all(r.converged for r in reports)
     assert all(r.precision == "f32" for r in reports)  # solver-level merge
+
+
+# -- no f64 path (a TPU): refusal and stalled reports ------------------------
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer the backend checks to a TPU's answers: f64 emulated (so
+    the ``f64`` policy is refused and ``mixed`` is the default)."""
+    monkeypatch.setattr(precision_mod, "emulates_f64", lambda b=None: True)
+
+
+@pytest.mark.parametrize(
+    "build",
+    [
+        lambda: ElasticityService(precision="f64"),
+        lambda: ElasticityService(max_batch=1).submit(
+            SolveRequest(p=1, refine=0, precision="f64")
+        ),
+        lambda: BatchedGMGSolver(beam_hex(), 0, 1, precision="f64"),
+        lambda: solve_beam(1, 0, dtype=jnp.float64),
+    ],
+    ids=["service", "submit", "solver", "solve_beam"],
+)
+def test_tpu_refuses_f64_naming_mixed(on_tpu, build):
+    """The f64 V-cycle does not compile in a usable time for the p=8
+    beam on a TPU: every entry point refuses it up front, naming the
+    policy to use, and never lowers precision on its own."""
+    with pytest.raises(ValueError, match="precision='mixed'"):
+        build()
+
+
+def test_tpu_default_policy_is_mixed(on_tpu):
+    assert resolve_precision(None) is PRECISION_POLICIES["mixed"]
+    svc = ElasticityService(max_batch=1)
+    assert svc.precision.name == "mixed"
+    assert svc.fallback_precision is None
+    # an explicit dtype is a request, not a default: still refused
+    with pytest.raises(ValueError, match="precision='mixed'"):
+        ElasticityService(dtype=jnp.float64)
+
+
+def test_compiled_lane_service_has_no_f64_fallback(on_tpu, monkeypatch):
+    """A paop_pallas f32 service on the compiled lane is built (no
+    kernel runs here) with no fallback policy, so a stalled row can only
+    retire as a stalled report — never by building an f64 solver."""
+    monkeypatch.setattr(ops, "backend_supports_compiled", lambda b=None: True)
+    svc = ElasticityService(assembly="paop_pallas", precision="f32")
+    assert svc.pallas_lane == "compiled"
+    assert svc.fallback_precision is None
+    with pytest.raises(ValueError, match="Mosaic has no float64"):
+        svc.submit(SolveRequest(p=1, refine=0, precision="mixed"))
+
+
+@pytest.mark.parametrize("path", ["continuous", "generational"])
+def test_stall_without_f64_path_is_reported_not_raised(on_tpu, path):
+    """A planted stall (1e-13 is below f32's floor) with no f64 path to
+    fall back on: the service neither raises nor hides it — the row
+    retires unconverged with ``stalled=True`` and its neighbour is
+    untouched."""
+    svc = ElasticityService(max_batch=2, precision="f32")
+    reqs = [
+        SolveRequest(p=1, refine=0, rel_tol=1e-4),
+        SolveRequest(p=1, refine=0, rel_tol=1e-13),
+    ]
+    run = svc.solve_continuous if path == "continuous" else svc.solve
+    ok, hard = run(reqs)
+    assert ok.converged and not ok.stalled and not ok.fallback
+    assert not hard.converged and hard.stalled and not hard.fallback
+    assert hard.precision == "f32"
+    assert hard.final_rel_norm > hard.request.rel_tol
+    assert svc.stats["precision_fallbacks"] == 0
+    assert svc.idle()
 
 
 # -- the policy axis of the compile cache ------------------------------------

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.base import ShapeConfig, get_reduced
 from repro.launch.train import train_loop
@@ -98,10 +99,8 @@ def test_local_cell_lowering():
     dry run."""
     from repro.launch.cells import build_cell
 
-    from repro.launch.mesh import axis_type_kwargs
-
     mesh = jax.make_mesh(
-        (1, 1), ("data", "model"), **axis_type_kwargs(2)
+        (1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )
     import repro.configs.base as base
 
