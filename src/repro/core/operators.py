@@ -58,7 +58,13 @@ from repro.distributed.sharding import pin_scenario
 from repro.fem.bc import ConstrainedOperator
 from repro.fem.space import H1Space
 
-__all__ = ["ElasticityOperator", "ASSEMBLY_LEVELS", "DEFER_MATERIALS"]
+__all__ = [
+    "ElasticityOperator", "ASSEMBLY_LEVELS", "DEFER_MATERIALS", "PA_APPLY"
+]
+
+# ``jax.named_scope`` name of every operator apply; it nests under the
+# scope of the caller (the outer Krylov, a smoother, a residual, ...).
+PA_APPLY = "pa.apply"
 
 # Sentinel: build the operator as a geometry/tables carrier only; material
 # fields are bound later via with_materials (e.g. inside a jitted batched
@@ -339,28 +345,30 @@ class ElasticityOperator:
     def apply(self, x):
         """Unconstrained y = A x on the L-vector (nscalar, 3), or the
         scenario batch (S, nscalar, 3) for a batched operator."""
-        if self.assembly == "fa":
-            y = self._sparse.matvec(x.reshape(-1))
-            return y.reshape(x.shape)
-        if self.nbatch is not None:
-            s, ne = self.nbatch, self.space.nelem
-            x = pin_scenario(x, self.shard_mesh)
-            x_e = jax.vmap(self.space.to_evec)(x)  # (S, ne, 3, D, D, D)
-            # Pin the folded (S*ne, ...) E-vector: each shard holds whole
-            # scenarios, so the fused PA/Pallas kernel below is purely
-            # shard-local.
-            x_e = pin_scenario(
-                x_e.reshape((s * ne,) + x_e.shape[2:]), self.shard_mesh
-            )
+        with jax.named_scope(PA_APPLY):
+            if self.assembly == "fa":
+                y = self._sparse.matvec(x.reshape(-1))
+                return y.reshape(x.shape)
+            if self.nbatch is not None:
+                s, ne = self.nbatch, self.space.nelem
+                x = pin_scenario(x, self.shard_mesh)
+                # (S, ne, 3, D, D, D)
+                x_e = jax.vmap(self.space.to_evec)(x)
+                # Pin the folded (S*ne, ...) E-vector: each shard holds
+                # whole scenarios, so the fused PA/Pallas kernel below is
+                # purely shard-local.
+                x_e = pin_scenario(
+                    x_e.reshape((s * ne,) + x_e.shape[2:]), self.shard_mesh
+                )
+                y_e = self._apply_evec(x_e)
+                y_e = pin_scenario(y_e, self.shard_mesh)
+                y_e = y_e.reshape((s, ne) + y_e.shape[1:])
+                return pin_scenario(
+                    jax.vmap(self.space.scatter_add)(y_e), self.shard_mesh
+                )
+            x_e = self.space.to_evec(x)
             y_e = self._apply_evec(x_e)
-            y_e = pin_scenario(y_e, self.shard_mesh)
-            y_e = y_e.reshape((s, ne) + y_e.shape[1:])
-            return pin_scenario(
-                jax.vmap(self.space.scatter_add)(y_e), self.shard_mesh
-            )
-        x_e = self.space.to_evec(x)
-        y_e = self._apply_evec(x_e)
-        return self.space.scatter_add(y_e)
+            return self.space.scatter_add(y_e)
 
     def __call__(self, x):
         return self.apply(x)

@@ -209,9 +209,10 @@ def main() -> None:
                          "Prometheus text dump (.prom/.txt) or JSON "
                          "snapshot (.json)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="record request/chunk spans (device-fenced) and "
-                         "write a Chrome trace_event file — open it at "
-                         "https://ui.perfetto.dev")
+                    help="record request and host-work spans (no "
+                         "device sync; device time needs a jax.profiler "
+                         "trace) and write a Chrome trace_event file — "
+                         "open it at https://ui.perfetto.dev")
     ap.add_argument("--events-out", default=None, metavar="PATH",
                     help="also write the spans as a JSON-lines event log")
     ap.add_argument("--checkpoint-dir", default=None, metavar="DIR",

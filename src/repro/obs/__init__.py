@@ -7,9 +7,10 @@ The measurement layer every perf PR is judged with:
   ``(p, refine, policy, devices)``, with snapshot/merge/diff semantics
   and Prometheus-text + JSON export.  ``ElasticityService.stats`` is a
   read-only view over one of these.
-* :mod:`repro.obs.spans` — per-request lifecycle spans and per-chunk
-  device-fenced timing, exportable as a JSON-lines event log and a
-  Chrome ``trace_event`` file viewable in Perfetto.
+* :mod:`repro.obs.spans` — per-request lifecycle spans and the
+  service's host spans on the profiler's clock, exportable as a
+  JSON-lines event log and a Chrome ``trace_event`` file viewable in
+  Perfetto.
 * :mod:`repro.obs.throughput` — kernel-level operator apply throughput
   (DoF/s, effective GB/s against the streaming-bytes model) on the
   batched path; feeds ``benchmarks/operator_sweep.py`` and the

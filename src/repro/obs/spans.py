@@ -1,4 +1,4 @@
-"""Span recording: request lifecycles and device-fenced chunk timing.
+"""Span recording: request lifecycles and the service's host work.
 
 A :class:`SpanRecorder` collects closed intervals (``Span``\\ s) from the
 serving stack and exports them two ways:
@@ -7,28 +7,22 @@ serving stack and exports them two ways:
   per line, trivially greppable / streamable;
 * a **Chrome ``trace_event`` file** (:meth:`SpanRecorder.to_chrome_trace`)
   — open it at https://ui.perfetto.dev to see the engine's timeline:
-  one track per in-flight discretization key (prep + chunk spans, the
-  chunk split into host ``dispatch`` and device-fenced ``device``
-  phases) and one track per batch slot (``queue_wait`` then ``solve``
-  per request riding that slot).
+  one track for the engine's steps, one per in-flight discretization key
+  (retire, admit, prep and dispatch spans) and one per batch slot
+  (``queue_wait`` then ``solve`` per request riding that slot).
 
 The service's span taxonomy and the meaning of every ``args`` field are
 cataloged in ``docs/OBSERVABILITY.md``.
 
-Device fencing: jax dispatch is asynchronous, so wall-clock around a
-``run_chunk`` call measures *host dispatch*, not compute.  When a
-recorder is installed the service fences each chunk with
-``jax.block_until_ready`` on the returned state — splitting dispatch
-from device compute — WITHOUT fetching the deferred per-row consumed
-vector (fencing waits for completion; it does not transfer), so the
-PR-5 contract that the consumed fetch rides the next retire pass is
-preserved.  With no recorder installed there is no fence and no
-per-chunk sync at all (see the instrumentation-overhead guard in
-``tests/test_obs.py``).
-
-``clock`` is injectable (default ``time.perf_counter``); the injected-
-clock tests drive it deterministically and assert the lifecycle
-identity *queue_wait + compute + overhead == wall* per ticket exactly.
+Clock: :func:`profiler_clock` by default, the clock ``jax.profiler``
+stamps its host events with, so the service's spans line up with the
+``service.*`` annotations and the device's operations in a profiler
+trace.  Recording never waits on the device: jax dispatch is
+asynchronous, so a ``dispatch`` span is host time only, and device time
+comes from the profiler's trace.  ``clock`` is injectable; the
+injected-clock tests drive it deterministically and assert the lifecycle
+identity *queue_wait + solve == submit-to-retire wall* per ticket
+exactly.
 """
 
 from __future__ import annotations
@@ -38,9 +32,16 @@ import json
 import time
 from typing import Any
 
-__all__ = ["Span", "SpanRecorder"]
+__all__ = ["Span", "SpanRecorder", "profiler_clock"]
 
 EVENTS_SCHEMA = "repro.obs.spans/v1"
+
+
+def profiler_clock() -> float:
+    """Seconds on the profiler's clock: ``jax.profiler`` stamps host
+    events with the realtime clock (nanoseconds since the epoch; a trace
+    stores them relative to its ``profile_start_time``)."""
+    return time.time_ns() * 1e-9
 
 
 @dataclasses.dataclass
@@ -63,15 +64,10 @@ class Span:
 
 class SpanRecorder:
     """Collects spans; tracks open begin/end pairs so a leak is
-    detectable (``open_count`` must be 0 when the engine is idle).
+    detectable (``open_count`` must be 0 when the engine is idle)."""
 
-    ``fence=True`` (default) asks the service to device-fence each
-    chunk so dispatch and compute separate; ``fence=False`` records
-    host-side dispatch times only (no extra synchronization)."""
-
-    def __init__(self, clock=time.perf_counter, fence: bool = True):
+    def __init__(self, clock=profiler_clock):
         self.clock = clock
-        self.fence = fence
         self.spans: list[Span] = []
         self._open: dict[int, Span] = {}
         self._next_id = 0

@@ -74,19 +74,22 @@ Shared machinery:
   view), request latency and queue wait feed registry histograms
   (``latency_summary()`` reports the merged quantiles), and attaching a
   :class:`repro.obs.spans.SpanRecorder` (``attach_spans``) records the
-  full request lifecycle — submit→admit→prep→chunk*→retire — with
-  device-fenced per-chunk timing, exportable as a Chrome trace and a
-  JSON-lines event log.  The service clock is injectable for
-  deterministic tests.  Catalog: ``docs/OBSERVABILITY.md``.
+  full request lifecycle — submit→admit→prep→dispatch→retire —
+  exportable as a Chrome trace and a JSON-lines event log.  The host
+  work of every step also opens ``service.<name>`` profiler annotations
+  (:data:`SERVICE_SPANS`), so a ``jax.profiler`` trace names it beside
+  the device's scoped work; recording adds no device sync.  The service
+  clock (default: the profiler's) is injectable for deterministic
+  tests.  Catalog: ``docs/OBSERVABILITY.md``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from collections import OrderedDict, deque
 from collections.abc import Mapping
+from contextlib import contextmanager
 from typing import Any
 
 import jax
@@ -117,9 +120,22 @@ from repro.serve.chunk_policy import (
     wasted_iterations,
 )
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.spans import profiler_clock
 from repro.solvers.batched import BatchedGMGSolver, BpcgState
 
-__all__ = ["SolveRequest", "SolveReport", "ElasticityService"]
+__all__ = ["SolveRequest", "SolveReport", "ElasticityService", "SERVICE_SPANS"]
+
+# The service's host spans: each opens a ``service.<name>`` profiler
+# annotation and, with a SpanRecorder attached, records ``<name>`` there.
+SERVICE_SPANS = (
+    "step",  # one step(): every flight's retire, admit, prep, dispatch
+    "retire",  # consumed + state fetches (the host waits on the device)
+    "admit",  # refill: material packing and digests
+    "prep",  # digest match + the prepare / copy_prep_rows dispatch
+    "dispatch",  # the run_chunk call
+    "build",  # a solver cache miss: hierarchy + program construction
+    "generation",  # one generational batch, solved and fetched
+)
 
 # Help text for the service counter families.  The keys double as the
 # legacy ``ElasticityService.stats`` vocabulary: each maps to the
@@ -298,16 +314,12 @@ class _Slot:
     """A live batch row: which request occupies it and since when.
 
     ``t_submit`` carries the ticket's enqueue time so retirement can
-    attribute queue wait; ``t_compute`` / ``t_padding`` accumulate this
-    row's share of device-fenced chunk time and of the padding fraction
-    of it (only maintained while a fencing SpanRecorder is attached)."""
+    attribute queue wait."""
 
     ticket: int
     request: SolveRequest
     t_admit: float
     t_submit: float = 0.0
-    t_compute: float = 0.0
-    t_padding: float = 0.0
 
 
 class _Flight:
@@ -387,7 +399,7 @@ class ElasticityService:
         mesh=None,
         registry: MetricsRegistry | None = None,
         spans=None,
-        clock=time.perf_counter,
+        clock=profiler_clock,
     ):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
@@ -500,15 +512,27 @@ class ElasticityService:
         return self.watchdog
 
     def attach_spans(self, recorder) -> None:
-        """Install a :class:`repro.obs.spans.SpanRecorder`.  With
-        ``recorder.fence`` set, every continuous chunk is fenced with
-        ``jax.block_until_ready`` on the returned state — separating
-        host dispatch from device compute WITHOUT fetching the deferred
-        consumed vector (fencing waits; the fetch still rides the next
-        retire pass).  With no recorder attached the service adds no
-        fences and no per-chunk timing at all."""
+        """Install a :class:`repro.obs.spans.SpanRecorder`: the host
+        spans of :data:`SERVICE_SPANS` and the per-request
+        ``queue_wait`` / ``solve`` spans are recorded there, stamped by
+        the service clock.  Recording adds no device sync and changes no
+        scheduling decision; device time comes from a profiler trace."""
         self.spans = recorder
         recorder.thread_name(0, "engine")
+
+    @contextmanager
+    def _span(self, name: str, *, cat: str = "engine", tid: int = 0, **args):
+        """One host span: a ``service.<name>`` profiler annotation (next
+        to free while no profiler runs) and, with a recorder attached,
+        the same interval recorded as ``name``.  The body may add args
+        to the yielded dict."""
+        rec = self.spans
+        t0 = self.clock() if rec is not None else 0.0
+        with jax.profiler.TraceAnnotation(f"service.{name}"):
+            yield args
+        if rec is not None:
+            rec.emit(name, cat=cat, tid=tid, start=t0, end=self.clock(),
+                     **args)
 
     def _labels(self, key: tuple) -> dict:
         """The uniform service label set for a flight key."""
@@ -646,16 +670,17 @@ class ElasticityService:
             return self._solvers[key], True, 0.0
         t0 = self.clock()
         cmesh = req.coarse_mesh if req.coarse_mesh is not None else beam_hex()
-        solver = BatchedGMGSolver(
-            cmesh,
-            req.refine,
-            req.p,
-            assembly=self.assembly,
-            precision=self._policy_for(req),
-            maxiter=self.maxiter,
-            pallas_lane=self.pallas_lane,
-            mesh=self.mesh,
-        )
+        with self._span("build", p=req.p, refine=req.refine):
+            solver = BatchedGMGSolver(
+                cmesh,
+                req.refine,
+                req.p,
+                assembly=self.assembly,
+                precision=self._policy_for(req),
+                maxiter=self.maxiter,
+                pallas_lane=self.pallas_lane,
+                mesh=self.mesh,
+            )
         self._solvers[key] = solver
         self._inc("cache_misses", key)
         while len(self._solvers) > self.cache_size:
@@ -690,15 +715,18 @@ class ElasticityService:
         ``watchdog_fires`` counter and a span without interrupting the
         step itself (detection, not preemption; escalation is the
         callback's job)."""
-        if self.watchdog is not None:
-            with self.watchdog.step():
-                return self._step_body()
-        return self._step_body()
+        with self._span("step", step=self._step_index + 1) as args:
+            if self.watchdog is not None:
+                with self.watchdog.step():
+                    completed = self._step_body()
+            else:
+                completed = self._step_body()
+            args["completed"] = completed
+        return completed
 
     def _step_body(self) -> int:
         self._step_index += 1
         rec = self.spans
-        t_step0 = self.clock() if rec is not None else 0.0
         done_before = len(self._completed)
         qgroups: OrderedDict[tuple, list[tuple[int, SolveRequest]]] = (
             OrderedDict()
@@ -722,11 +750,13 @@ class ElasticityService:
                         flight.tid_base,
                         f"flight p={key[0]} refine={key[1]}",
                     )
-            self._retire(flight)
+            with self._span("retire", cat="flight", tid=flight.tid_base):
+                self._retire(flight)
             if not flight.live_rows() and not queued:
                 del self._flights[key]
                 continue
-            admitted |= self._admit(flight, queued)
+            with self._span("admit", cat="flight", tid=flight.tid_base):
+                admitted |= self._admit(flight, queued)
             if flight.live_rows():
                 self._launch_chunk(flight)
             else:
@@ -735,18 +765,7 @@ class ElasticityService:
             self._queue = [
                 (t, r) for t, r in self._queue if t not in admitted
             ]
-        completed = len(self._completed) - done_before
-        if rec is not None:
-            rec.emit(
-                "step",
-                cat="engine",
-                tid=0,
-                start=t_step0,
-                end=self.clock(),
-                step=self._step_index,
-                completed=completed,
-            )
-        return completed
+        return len(self._completed) - done_before
 
     def _flight_tid(self) -> int:
         """Next flight's Chrome-trace track block: tid 0 is the engine;
@@ -864,11 +883,8 @@ class ElasticityService:
                 wall,
             )
             if rec is not None:
-                # Lifecycle identity per ticket: queue_wait + compute +
-                # overhead == submit-to-retire wall, exactly (compute is
-                # this row's share of device-fenced chunk time; overhead
-                # is everything else — host scheduling, dispatch,
-                # retire/refill bookkeeping).
+                # Per ticket, queue_wait + this span == submit-to-retire
+                # wall, exactly: both are stamped by the service clock.
                 rec.emit(
                     "solve",
                     cat="request",
@@ -879,9 +895,6 @@ class ElasticityService:
                     iterations=int(iters[i]),
                     converged=converged,
                     queue_wait=slot.t_admit - slot.t_submit,
-                    compute=slot.t_compute,
-                    overhead=wall - slot.t_compute,
-                    padding_overhead=slot.t_padding,
                 )
             fell_back = slot.ticket in self._fallback_tickets
             self._fallback_tickets.discard(slot.ticket)
@@ -1058,17 +1071,16 @@ class ElasticityService:
         flight.pending_refills = tuple(refills)
         return admitted
 
-    def _refresh_prep(self, flight: _Flight, reset: np.ndarray) -> None:
+    def _refresh_prep(self, flight: _Flight, reset: np.ndarray) -> dict:
         """Make every reset row's prep match its (new) materials.  Rows
         whose folded per-element fields content-match an already-valid
         row — digest equality first (O(1) per candidate, heterogeneous
         fields included), confirmed bitwise against the snapshot — reuse
         that row's derived data with a cheap device gather (prep depends
         only on materials); only genuinely new material configurations
-        pay the ``prepare`` power iterations + refactorization."""
+        pay the ``prepare`` power iterations + refactorization.  Returns
+        the row counts the ``prep`` span records."""
         solver = flight.solver
-        rec = self.spans
-        t_prep0 = self.clock() if rec is not None else 0.0
         src_rows, dst_rows, unresolved = [], [], []
         sources = [s for s in range(flight.bucket) if flight.prep_valid[s]]
         for r in np.flatnonzero(reset):
@@ -1110,17 +1122,11 @@ class ElasticityService:
         flight.prep_digest[reset] = flight.mat_digest[reset]
         flight.prep_lam[reset] = flight.lam[reset]
         flight.prep_mu[reset] = flight.mu[reset]
-        if rec is not None:
-            rec.emit(
-                "prep",
-                cat="flight",
-                tid=flight.tid_base,
-                start=t_prep0,
-                end=self.clock(),
-                rows_reset=int(reset.sum()),
-                rows_copied=len(dst_rows),
-                rows_prepared=len(unresolved),
-            )
+        return {
+            "rows_reset": int(reset.sum()),
+            "rows_copied": len(dst_rows),
+            "rows_prepared": len(unresolved),
+        }
 
     def _launch_chunk(self, flight: _Flight) -> None:
         """One bounded advance of the flight's compiled step program,
@@ -1133,7 +1139,8 @@ class ElasticityService:
         reset = flight.pending_reset
         do_reset = reset is not None
         if do_reset:
-            self._refresh_prep(flight, reset)
+            with self._span("prep", cat="flight", tid=flight.tid_base) as a:
+                a.update(self._refresh_prep(flight, reset))
             flight.row_iters[reset] = 0
         mask = (
             reset if do_reset else np.zeros((flight.bucket,), dtype=bool)
@@ -1148,67 +1155,17 @@ class ElasticityService:
             n_devices=self.n_shards,
         )
         k = self.chunk_policy.chunk_for(obs)
-        rec = self.spans
-        t0 = self.clock() if rec is not None else 0.0
-        flight.state, flight.pending_consumed = solver.run_chunk(
-            flight.tr,
-            flight.tol,
-            mask,
-            flight.state,
-            flight.prep,
-            k,
-            do_reset=do_reset,
-        )
-        if rec is not None:
-            t_dispatched = self.clock()
-            rec.emit(
-                "chunk_dispatch",
-                cat="chunk",
-                tid=flight.tid_base,
-                start=t0,
-                end=t_dispatched,
-                chunk=k,
-                bucket=flight.bucket,
-                live=len(live),
+        with self._span("dispatch", cat="chunk", tid=flight.tid_base,
+                        chunk=k, bucket=flight.bucket, live=len(live)):
+            flight.state, flight.pending_consumed = solver.run_chunk(
+                flight.tr,
+                flight.tol,
+                mask,
+                flight.state,
+                flight.prep,
+                k,
+                do_reset=do_reset,
             )
-            if rec.fence:
-                # Fence, don't fetch: block_until_ready waits for the
-                # chunk's computation (state AND the consumed vector it
-                # shares a program with) without transferring anything —
-                # the deferred consumed fetch still happens at the next
-                # retire pass, exactly as without instrumentation.
-                jax.block_until_ready(flight.state)
-                t_done = self.clock()
-                dt_dev = t_done - t_dispatched
-                rec.emit(
-                    "chunk_device",
-                    cat="chunk",
-                    tid=flight.tid_base,
-                    start=t_dispatched,
-                    end=t_done,
-                    chunk=k,
-                    bucket=flight.bucket,
-                    live=len(live),
-                )
-                self._observe(
-                    "chunk_device_seconds",
-                    "Device-fenced wall time per continuous chunk.",
-                    flight.key,
-                    dt_dev,
-                )
-                # Attribute this chunk's device time to the rows that
-                # rode it: each live ticket accrues the full chunk wall
-                # as compute, plus its per-ticket share of the padding
-                # fraction (padded rows / bucket) as padding overhead.
-                n_live = len(live)
-                pad_share = (
-                    dt_dev * (flight.bucket - n_live) / flight.bucket / n_live
-                    if n_live
-                    else 0.0
-                )
-                for i in live:
-                    flight.slots[i].t_compute += dt_dev
-                    flight.slots[i].t_padding += pad_share
         decision = ChunkDecision(
             step=self._step_index,
             key=flight.key,
@@ -1288,8 +1245,10 @@ class ElasticityService:
         )
 
         t0 = self.clock()
-        res = solver.solve(materials, tractions, rel_tols)
-        x = res.x.block_until_ready()
+        with self._span("generation", cat="generation", generation=generation,
+                        batch=n_real, padded_rows=n_real + n_pad):
+            res = solver.solve(materials, tractions, rel_tols)
+            x = res.x.block_until_ready()
         t_solve = self.clock() - t0
         self._inc("generations", key)
         for _ in reqs:
@@ -1299,18 +1258,6 @@ class ElasticityService:
                 key,
                 t_solve,
             )
-        if self.spans is not None:
-            self.spans.emit(
-                "generation",
-                cat="generation",
-                tid=0,
-                start=t0,
-                end=t0 + t_solve,
-                generation=generation,
-                batch=n_real,
-                padded_rows=n_real + n_pad,
-            )
-
         iters = np.asarray(res.iterations)
         conv = np.asarray(res.converged)
         fin = np.asarray(res.final_norm)

@@ -106,7 +106,26 @@ __all__ = [
     "BpcgState",
     "BPCGResult",
     "BatchedGMGSolver",
+    "PCG_INIT",
+    "PCG_APPLY",
+    "PCG_PRECOND",
+    "PCG_VECTOR",
+    "PCG_AUDIT",
+    "PREP",
+    "PREP_COARSE",
 ]
+
+# ``jax.named_scope`` names of the step and prep programs' device work;
+# a profiler trace carries them as each operation's scope path.  The
+# V-cycle nests its own (``repro.solvers.gmg``), and the operator apply
+# nests ``pa.apply`` (``repro.core.operators``) under whichever called it.
+PCG_INIT = "pcg.init"  # row (re)initialization: bpcg_init + merge_states
+PCG_APPLY = "pcg.apply"  # the outer Krylov operator A(d)
+PCG_PRECOND = "pcg.precond"  # the V-cycle M(r), casts included
+PCG_VECTOR = "pcg.vector"  # dots, axpys, masks, stall bookkeeping
+PCG_AUDIT = "pcg.audit"  # true_residual_audit
+PREP = "prep"  # the prep program: material folds, smoother data
+PREP_COARSE = "prep.coarse"  # coarse probe + Cholesky factor
 
 
 @jax.tree_util.register_dataclass
@@ -237,55 +256,60 @@ def bpcg_chunk(
 
     def cond(carry):
         st, step = carry
-        go = jnp.any(st.active)
-        if k_iters is not None:
-            go = go & (step < k_iters)
+        with jax.named_scope(PCG_VECTOR):
+            go = jnp.any(st.active)
+            if k_iters is not None:
+                go = go & (step < k_iters)
         return go
 
     def body(carry):
         st, step = carry
         x, r, nom, active = st.x, st.r, st.nom, st.active
-        ad = A(st.d)
-        den = _dots(st.d, ad)
-        # Inactive rows get alpha = 0 (frozen); den == 0 cannot occur for
-        # an active SPD row (d != 0 there) but is guarded so one bad or
-        # retired scenario can never NaN the rest of the batch.
-        ok = active & (den > 0)
-        alpha = jnp.where(ok, nom / jnp.where(den == 0, 1.0, den), 0.0)
-        x = x + _col(alpha, x.ndim) * st.d
-        r = r - _col(alpha, r.ndim) * ad
-        z = M(r)
-        betanom = _dots(z, r)
-        beta = jnp.where(ok, betanom / jnp.where(nom == 0, 1.0, nom), 0.0)
-        d = jnp.where(
-            _col(active, st.d.ndim), z + _col(beta, st.d.ndim) * st.d, st.d
-        )
-        nom = jnp.where(active, betanom, nom)
-        # Count only real steps (ok), matching scalar pcg: an aborted
-        # degenerate direction (den <= 0) takes no step and adds none.
-        iters = st.iters + ok.astype(jnp.int32)
-        active = ok & (nom > st.threshold) & (iters < maxiter)
-        if stall_iters > 0:
-            # Progress = the best-seen nom dropped by >= (1 - rtol);
-            # best-so-far (not last-step) so an oscillating residual
-            # doesn't reset the counter on every upswing.
-            improved = betanom < st.best * stall_rtol
-            stall = jnp.where(
-                ok, jnp.where(improved, 0, st.stall + 1), st.stall
+        with jax.named_scope(PCG_APPLY):
+            ad = A(st.d)
+        with jax.named_scope(PCG_VECTOR):
+            den = _dots(st.d, ad)
+            # Inactive rows get alpha = 0 (frozen); den == 0 cannot occur
+            # for an active SPD row (d != 0 there) but is guarded so one
+            # bad or retired scenario can never NaN the rest of the batch.
+            ok = active & (den > 0)
+            alpha = jnp.where(ok, nom / jnp.where(den == 0, 1.0, den), 0.0)
+            x = x + _col(alpha, x.ndim) * st.d
+            r = r - _col(alpha, r.ndim) * ad
+        with jax.named_scope(PCG_PRECOND):
+            z = M(r)
+        with jax.named_scope(PCG_VECTOR):
+            betanom = _dots(z, r)
+            beta = jnp.where(ok, betanom / jnp.where(nom == 0, 1.0, nom), 0.0)
+            d = jnp.where(
+                _col(active, st.d.ndim), z + _col(beta, st.d.ndim) * st.d, st.d
             )
-            best = jnp.where(ok, jnp.minimum(st.best, betanom), st.best)
-            hit = active & (stall >= stall_iters)
-            stalled = st.stalled | hit
-            active = active & ~hit
-            new = dataclasses.replace(
-                st, x=x, r=r, z=z, d=d, nom=nom, iters=iters,
-                active=active, best=best, stall=stall, stalled=stalled,
-            )
-        else:
-            new = dataclasses.replace(
-                st, x=x, r=r, z=z, d=d, nom=nom, iters=iters, active=active
-            )
-        return (new, step + 1)
+            nom = jnp.where(active, betanom, nom)
+            # Count only real steps (ok), matching scalar pcg: an aborted
+            # degenerate direction (den <= 0) takes no step and adds none.
+            iters = st.iters + ok.astype(jnp.int32)
+            active = ok & (nom > st.threshold) & (iters < maxiter)
+            if stall_iters > 0:
+                # Progress = the best-seen nom dropped by >= (1 - rtol);
+                # best-so-far (not last-step) so an oscillating residual
+                # doesn't reset the counter on every upswing.
+                improved = betanom < st.best * stall_rtol
+                stall = jnp.where(
+                    ok, jnp.where(improved, 0, st.stall + 1), st.stall
+                )
+                best = jnp.where(ok, jnp.minimum(st.best, betanom), st.best)
+                hit = active & (stall >= stall_iters)
+                stalled = st.stalled | hit
+                active = active & ~hit
+                new = dataclasses.replace(
+                    st, x=x, r=r, z=z, d=d, nom=nom, iters=iters,
+                    active=active, best=best, stall=stall, stalled=stalled,
+                )
+            else:
+                new = dataclasses.replace(
+                    st, x=x, r=r, z=z, d=d, nom=nom, iters=iters, active=active
+                )
+            return (new, step + 1)
 
     state, _ = jax.lax.while_loop(
         cond, body, (state, jnp.zeros((), dtype=jnp.int32))
@@ -325,15 +349,18 @@ def true_residual_audit(
     fallback.  Rows passing the audit keep their state bitwise.  Never
     run on the f64 path (drift there is below any meaningful
     tolerance — and the extra A/M application isn't free)."""
-    claimed = ~state.active & (state.nom <= state.threshold) & ~state.stalled
-    rt = b - A(state.x)
-    nomt = _dots(M(rt), rt)
-    lying = claimed & (nomt > state.threshold * slack)
-    return dataclasses.replace(
-        state,
-        nom=jnp.where(lying, nomt, state.nom),
-        stalled=state.stalled | lying,
-    )
+    with jax.named_scope(PCG_AUDIT):
+        claimed = (
+            ~state.active & (state.nom <= state.threshold) & ~state.stalled
+        )
+        rt = b - A(state.x)
+        nomt = _dots(M(rt), rt)
+        lying = claimed & (nomt > state.threshold * slack)
+        return dataclasses.replace(
+            state,
+            nom=jnp.where(lying, nomt, state.nom),
+            stalled=state.stalled | lying,
+        )
 
 
 def _merge_fallback_rows(res: BPCGResult, sub: BPCGResult, rows) -> BPCGResult:
@@ -567,9 +594,12 @@ class BatchedGMGSolver:
         self._fine_ess = jnp.asarray(self._base_ops[-1].ess_mask)
         self._jit_solve = jax.jit(self._solve_impl)
         self._jit_prepare = jax.jit(self._prepare_impl)
-        self._jit_chunk = jax.jit(
-            self._chunk_impl, static_argnames=("do_reset",)
-        )
+        # One step program per chunk kind, so a device trace names which
+        # ran: the chunk that (re)starts rows and the one that resumes.
+        self._jit_chunk = {
+            True: jax.jit(self._chunk_start),
+            False: jax.jit(self._chunk_resume),
+        }
 
     @property
     def fine_space(self) -> H1Space:
@@ -912,14 +942,15 @@ class BatchedGMGSolver:
                 # factor at the coarse dtype — mixed-bf16 probes through
                 # a bf16 operator but holds the Cholesky at f32, where
                 # the factorization is still numerically viable.
-                K = probe_coarse_matrix(
-                    cop, sp.nscalar, s, self.precond_dtype,
-                    shard_mesh=self.mesh,
-                )
-                L = jnp.linalg.cholesky(K.astype(self.coarse_dtype))
-                chol = self._pin(
-                    jnp.where(reset_mask[:, None, None], L, prep["chol"])
-                )
+                with jax.named_scope(PREP_COARSE):
+                    K = probe_coarse_matrix(
+                        cop, sp.nscalar, s, self.precond_dtype,
+                        shard_mesh=self.mesh,
+                    )
+                    L = jnp.linalg.cholesky(K.astype(self.coarse_dtype))
+                    chol = self._pin(
+                        jnp.where(reset_mask[:, None, None], L, prep["chol"])
+                    )
             else:
                 sm = ChebyshevSmoother.setup(
                     cop,
@@ -1030,7 +1061,8 @@ class BatchedGMGSolver:
         )
 
     def _prepare_impl(self, lam_vals, mu_vals, reset_mask, prep) -> dict:
-        return self._prepare_body(lam_vals, mu_vals, reset_mask, prep)
+        with jax.named_scope(PREP):
+            return self._prepare_body(lam_vals, mu_vals, reset_mask, prep)
 
     def _chunk_impl(
         self, tractions, rel_tol, reset_mask, state, prep, k_iters,
@@ -1039,8 +1071,11 @@ class BatchedGMGSolver:
         state, prep = self._pin(state), self._pin(prep)
         levels, gmg, A, M = self._build_from_prep(prep)
         if do_reset:
-            fresh = bpcg_init(A, self._rhs(tractions), M=M, rel_tol=rel_tol)
-            state = merge_states(reset_mask, fresh, state)
+            with jax.named_scope(PCG_INIT):
+                fresh = bpcg_init(
+                    A, self._rhs(tractions), M=M, rel_tol=rel_tol
+                )
+                state = merge_states(reset_mask, fresh, state)
         start_iters = state.iters
         out = bpcg_chunk(
             A, state, M=M, k_iters=k_iters, maxiter=self.maxiter,
@@ -1053,13 +1088,26 @@ class BatchedGMGSolver:
         # host never has to fetch the full state mid-flight.
         return self._pin(out), self._pin(out.iters - start_iters)
 
+    def _chunk_start(self, tractions, rel_tol, reset_mask, state, prep,
+                     k_iters):
+        return self._chunk_impl(tractions, rel_tol, reset_mask, state, prep,
+                                k_iters, do_reset=True)
+
+    def _chunk_resume(self, tractions, rel_tol, reset_mask, state, prep,
+                      k_iters):
+        return self._chunk_impl(tractions, rel_tol, reset_mask, state, prep,
+                                k_iters, do_reset=False)
+
     def _solve_impl(self, lam_vals, mu_vals, tractions, rel_tol):
         s = lam_vals.shape[0]
-        prep = self._prepare_body(
-            lam_vals, mu_vals, jnp.ones((s,), dtype=bool), self.empty_prep(s)
-        )
+        with jax.named_scope(PREP):
+            prep = self._prepare_body(
+                lam_vals, mu_vals, jnp.ones((s,), dtype=bool),
+                self.empty_prep(s),
+            )
         levels, gmg, A, M = self._build_from_prep(prep)
-        state = bpcg_init(A, self._rhs(tractions), M=M, rel_tol=rel_tol)
+        with jax.named_scope(PCG_INIT):
+            state = bpcg_init(A, self._rhs(tractions), M=M, rel_tol=rel_tol)
         state = bpcg_chunk(
             A, state, M=M, k_iters=None, maxiter=self.maxiter,
             stall_iters=self.stall_iters, stall_rtol=self.stall_rtol,
@@ -1169,9 +1217,9 @@ class BatchedGMGSolver:
         tractions, rel, reset_mask, state, prep = self._put(
             (tractions, rel, reset_mask, state, prep)
         )
-        return self._jit_chunk(
+        return self._jit_chunk[do_reset](
             tractions, rel, reset_mask, state, prep,
-            jnp.asarray(k_iters, dtype=jnp.int32), do_reset=do_reset,
+            jnp.asarray(k_iters, dtype=jnp.int32),
         )
 
     def _f64_fallback_solver(self) -> "BatchedGMGSolver":

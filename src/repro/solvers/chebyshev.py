@@ -25,7 +25,11 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import pin_scenario
 
-__all__ = ["ChebyshevSmoother", "power_iteration_lmax"]
+__all__ = ["ChebyshevSmoother", "power_iteration_lmax", "PREP_POWER"]
+
+# ``jax.named_scope`` name of the lambda_max power iterations, which run
+# in the prep program (``repro.solvers.batched.PREP``).
+PREP_POWER = "prep.power"
 
 
 def _expand(a, ndim: int):
@@ -68,8 +72,9 @@ def power_iteration_lmax(
         return (w, lam)
 
     lam0 = jnp.zeros(shape[:batch_dims], dtype)
-    v, lam = jax.lax.fori_loop(0, iters, body, (v, lam0))
-    return jnp.abs(lam)
+    with jax.named_scope(PREP_POWER):
+        v, lam = jax.lax.fori_loop(0, iters, body, (v, lam0))
+        return jnp.abs(lam)
 
 
 @dataclasses.dataclass

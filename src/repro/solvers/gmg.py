@@ -40,7 +40,21 @@ __all__ = [
     "build_hierarchy",
     "GMGPreconditioner",
     "Level",
+    "VCYCLE_COARSE",
+    "VCYCLE_PARTS",
+    "vcycle_scope",
 ]
+
+# ``jax.named_scope`` names of the V-cycle's device work: per level l
+# (finest = len(levels) - 1, coarsest = 0) its smoothing, residual and
+# the transfers between l and l - 1; the coarse solve below level 1.
+VCYCLE_COARSE = "vcycle.coarse"
+VCYCLE_PARTS = ("smooth", "residual", "restrict", "prolong")
+
+
+def vcycle_scope(level: int, part: str) -> str:
+    """The scope name of one part of the V-cycle on ``level``."""
+    return f"vcycle.L{level}.{part}"
 
 
 def p_chain(p_target: int) -> list[int]:
@@ -91,16 +105,22 @@ class GMGPreconditioner:
 
     def _vcycle(self, l: int, b):
         if l == 0:
-            return self.coarse_solve(b)
+            with jax.named_scope(VCYCLE_COARSE):
+                return self.coarse_solve(b)
         lev = self.levels[l]
-        x = lev.smoother(b)  # pre-smooth from zero initial guess
-        r = b - lev.constrained(x)
         t = self.transfers[l - 1]
-        rc = t.restrict(r)
-        rc = jnp.where(jnp.asarray(self.levels[l - 1].ess_mask), 0.0, rc)
+        with jax.named_scope(vcycle_scope(l, "smooth")):
+            x = lev.smoother(b)  # pre-smooth from zero initial guess
+        with jax.named_scope(vcycle_scope(l, "residual")):
+            r = b - lev.constrained(x)
+        with jax.named_scope(vcycle_scope(l, "restrict")):
+            rc = t.restrict(r)
+            rc = jnp.where(jnp.asarray(self.levels[l - 1].ess_mask), 0.0, rc)
         e = self._vcycle(l - 1, rc)
-        x = x + t.prolong(e)
-        x = lev.smoother(b, x)  # post-smooth
+        with jax.named_scope(vcycle_scope(l, "prolong")):
+            x = x + t.prolong(e)
+        with jax.named_scope(vcycle_scope(l, "smooth")):
+            x = lev.smoother(b, x)  # post-smooth
         return x
 
 

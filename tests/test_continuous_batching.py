@@ -362,7 +362,7 @@ def test_continuous_cache_hit_zero_retrace():
     key = service.group_key(reqs[0])
     solver = service._solvers[key]
     traces = (
-        solver._jit_chunk._cache_size(),
+        [j._cache_size() for j in solver._jit_chunk.values()],
         solver._jit_prepare._cache_size(),
     )
     hits0 = service.stats["cache_hits"]
@@ -372,7 +372,7 @@ def test_continuous_cache_hit_zero_retrace():
     assert second[0].cache_hit
     assert service.stats["cache_hits"] > hits0
     assert (
-        solver._jit_chunk._cache_size(),
+        [j._cache_size() for j in solver._jit_chunk.values()],
         solver._jit_prepare._cache_size(),
     ) == traces
     for ra, rb in zip(first, second):

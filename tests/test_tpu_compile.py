@@ -137,13 +137,12 @@ def test_chunk_program_compiles_for_v5e(one_chip, tpu_lane):
     state = jax.tree.map(sds, solver.empty_state(s))
     prep = jax.tree.map(sds, solver.empty_prep(s))
     row = jax.ShapeDtypeStruct((s,), np.float32, sharding=one_chip)
-    compiled = solver._jit_chunk.lower(
+    compiled = solver._jit_chunk[True].lower(
         jax.ShapeDtypeStruct((s, 3), np.float32, sharding=one_chip),
         row,
         jax.ShapeDtypeStruct((s,), np.bool_, sharding=one_chip),
         state,
         prep,
         jax.ShapeDtypeStruct((), np.int32, sharding=one_chip),
-        do_reset=True,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
