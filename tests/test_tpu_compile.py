@@ -14,6 +14,7 @@ worker imports this file.
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -146,3 +147,38 @@ def test_chunk_program_compiles_for_v5e(one_chip, tpu_lane):
         jax.ShapeDtypeStruct((), np.int32, sharding=one_chip),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
+def test_operator_apply_contractions_for_v5e(dtype, one_chip):
+    """A v5e has no float64 units: the f64 operator apply's contractions
+    compile to scoped multiply-adds, with no dot or convolution left for
+    XLA to emulate, while the f32 apply keeps its MXU convolutions."""
+    from repro.core import contract
+    from repro.core.operators import DEFER_MATERIALS, ElasticityOperator
+    from repro.fem.mesh import beam_hex
+    from repro.fem.space import H1Space
+
+    p = 8
+    space = H1Space(beam_hex().refined(), p)  # 64 elements
+    op = ElasticityOperator(
+        space, assembly="paop", materials=DEFER_MATERIALS, dtype=dtype
+    )
+
+    def apply(x, lam_w, mu_w):
+        return op.with_material_weights(lam_w, mu_w, None).apply(x)
+
+    q = (space.nelem,) + (p + 2,) * 3
+    compiled = jax.jit(apply).lower(
+        jax.ShapeDtypeStruct((space.nscalar, 3), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct(q, dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct(q, dtype, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    dots = re.findall(r" (?:convolution|dot)\(", text)
+    if dtype == jnp.float64:
+        assert not dots
+        assert contract.CONTRACT_F64 in text
+    else:
+        assert dots
+        assert contract.CONTRACT_F64 not in text
